@@ -436,12 +436,42 @@ def _call_int_literals(call: ast.Call, env: dict[str, int]) -> list[int]:
     return out
 
 
-def _builds_big_grid(fn: ast.AST, env: dict[str, int]) -> bool:
+#: a call to one of these only describes a shape (AOT compiles take
+#: them): no grid is allocated
+SHAPE_ONLY = {"ShapeDtypeStruct"}
+
+
+def _callee(call: ast.Call) -> Optional[str]:
+    f = call.func
+    return (f.id if isinstance(f, ast.Name)
+            else f.attr if isinstance(f, ast.Attribute) else None)
+
+
+def _shape_only_helpers(tree: ast.AST) -> set[str]:
+    """Module functions that just return a shape description
+    (``return jax.ShapeDtypeStruct(...)``) — their calls are not grids
+    either."""
+    out = set()
+    for node in ast.walk(tree):
+        if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
+            rets = [n for n in ast.walk(node) if isinstance(n, ast.Return)]
+            if rets and all(isinstance(r.value, ast.Call)
+                            and _callee(r.value) in SHAPE_ONLY
+                            for r in rets):
+                out.add(node.name)
+    return out
+
+
+def _builds_big_grid(fn: ast.AST, env: dict[str, int],
+                     shape_only: frozenset = frozenset()) -> bool:
     """True when some call in ``fn`` carries >= 2 int literals >=
     GRID_LIMIT — the >= 2048² grid-construction shape (one big literal
-    alone — a 1024x2048 strip, a byte count — does not trip it)."""
+    alone — a 1024x2048 strip, a byte count — does not trip it). Calls
+    that only describe a shape (``SHAPE_ONLY`` and helpers returning
+    one) allocate nothing and do not count."""
+    skip = SHAPE_ONLY | set(shape_only)
     for node in ast.walk(fn):
-        if isinstance(node, ast.Call):
+        if isinstance(node, ast.Call) and _callee(node) not in skip:
             big = [v for v in _call_int_literals(node, env)
                    if v >= GRID_LIMIT]
             if len(big) >= 2:
@@ -496,8 +526,10 @@ def _unmarked_heavy_tests(ctx: ModuleCtx) -> list[ast.AST]:
             funcs.setdefault(node.name, node)
 
     env = _const_env(tree)
+    shape_only = frozenset(_shape_only_helpers(tree))
     heavy = {name for name, fn in funcs.items()
-             if _directly_heavy(fn) or _builds_big_grid(fn, env)}
+             if _directly_heavy(fn)
+             or _builds_big_grid(fn, env, shape_only)}
     changed = True
     while changed:  # propagate through helper calls to a fixpoint
         changed = False

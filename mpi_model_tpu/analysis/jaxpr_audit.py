@@ -320,9 +320,10 @@ def _build_active_fused_runner() -> BuiltStep:
     plan = plan_for(space.shape)
     k = choose_fused_k(4, plan)
     rates = model.pallas_rates()
+    # interpret=True: the audit traces the same program on any machine
     run = build_fused_runner(space.shape, rates, model.offsets,
                              space.dtype, plan=plan, k=k,
-                             track_dirty=True)
+                             track_dirty=True, interpret=True)
     args = ({kk: _sds(v) for kk, v in space.values.items()},
             jax.ShapeDtypeStruct((), np.dtype("int32")))
     v0 = next(iter(space.values.values()))
@@ -420,8 +421,7 @@ def check_term_registry() -> list[Finding]:
 def _iter_eqns(jaxpr):
     """Every eqn in ``jaxpr`` and (recursively) in any sub-jaxpr held in
     eqn params (pjit/scan/while/cond/closed_call/pallas grids)."""
-    from ..compat import jaxpr_type
-    Jaxpr = jaxpr_type()
+    from jax.extend.core import Jaxpr
     for eqn in jaxpr.eqns:
         yield eqn
         for val in eqn.params.values():
@@ -454,8 +454,7 @@ def _iter_eqns_outside_pallas(jaxpr):
     kernel jaxpr — the fused-flags contract is about what runs OUTSIDE
     the kernel (in-kernel reductions over the VMEM-resident tile are
     the whole point)."""
-    from ..compat import jaxpr_type
-    Jaxpr = jaxpr_type()
+    from jax.extend.core import Jaxpr
     for eqn in jaxpr.eqns:
         yield eqn
         if "pallas" in eqn.primitive.name:
@@ -583,8 +582,7 @@ def audit_built(built: BuiltStep) -> list[Finding]:
     # all — are traced ARGUMENTS, never baked literals (a literal ids
     # buffer would freeze one activity pattern into the compile)
     if built.expect_prefetch_arg:
-        from ..compat import literal_type
-        Literal = literal_type()
+        from jax.extend.core import Literal
         n_pallas = 0
         for eqn in _iter_eqns(closed.jaxpr):
             if "pallas" not in eqn.primitive.name:

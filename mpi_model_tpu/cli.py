@@ -103,11 +103,11 @@ def _parse_chaos(args):
 
 def _cache_spec(args, default):
     """Map ``--compile-cache`` to the service knob: unset → ``default``
-    ("auto" on the ensemble/serve paths — the cache rides under the
-    scheduler by default; None on the single-run path, where it stays
-    opt-in), ``off``/``none`` → explicitly disabled, a directory →
-    that directory; an EMPTY value is an error, not a silent flip
-    (the errors-not-silent-no-ops rule)."""
+    ("auto": ``utils.compile_cache``'s placement rule),
+    ``off``/``none`` → explicitly disabled, a directory → that
+    directory unless ``$JAX_COMPILATION_CACHE_DIR`` is set; an EMPTY
+    value is an error, not a silent flip (the errors-not-silent-no-ops
+    rule)."""
     v = args.compile_cache
     if v is None:
         return default
@@ -196,15 +196,11 @@ def _pick_devices(n: int, hint_flag: str):
 
     devices = jax.devices()
     if len(devices) < n:
-        cpus = jax.devices("cpu")
-        if len(cpus) >= n:
-            devices = cpus
-        else:
-            raise SystemExit(
-                f"{hint_flag} needs {n} devices; have "
-                f"{len(devices)} (hint: XLA_FLAGS="
-                f"--xla_force_host_platform_device_count={n} "
-                "JAX_PLATFORMS=cpu for a virtual mesh)")
+        raise SystemExit(
+            f"{hint_flag} needs {n} devices; have {len(devices)} "
+            f"{devices[0].platform} device(s) (hint: XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={n} "
+            "JAX_PLATFORMS=cpu for a virtual CPU mesh)")
     return devices[:n]
 
 
@@ -504,10 +500,8 @@ def cmd_run(args) -> int:
     from .utils.compile_cache import configure_compile_cache
     from .utils.tracing import get_tracer
 
-    # arm the persistent compilation cache BEFORE anything compiles —
-    # idempotent; on the single-run path an unset flag leaves jax
-    # untouched (the ensemble/serve paths default to "auto" instead)
-    configure_compile_cache(_cache_spec(args, None))
+    # arm the persistent compilation cache BEFORE anything compiles
+    configure_compile_cache(_cache_spec(args, "auto"))
 
     # inapplicable flag combinations are errors, not silent no-ops — a
     # user must not believe they benchmarked a configuration that never
@@ -791,9 +785,9 @@ def cmd_run(args) -> int:
         ranks = report.comm_size
     wall = _time.perf_counter() - t0
 
-    # the kernel that ACTUALLY ran (after any "auto" fallback) — without
-    # this a silent fallback means the user benchmarked a configuration
-    # that never ran (round-3 VERDICT weak #2). --rectangular IS a
+    # the kernel that ACTUALLY ran (what "auto" resolved to) — without
+    # this the user could believe they benchmarked a configuration that
+    # never ran. --rectangular IS a
     # sharded run (a lines x columns block mesh), so the backend label
     # and the halo_depth/substeps applicability follow `sharded`, not
     # --mesh alone.

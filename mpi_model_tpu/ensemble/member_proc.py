@@ -1033,6 +1033,29 @@ def resolve_deadlines(transport: str,
     return float(heartbeat_deadline_s), float(rpc_deadline_s)
 
 
+def check_chip_free(env: dict) -> None:
+    """Refuse to spawn a member that would need the TPU this process
+    already holds. A TPU belongs to one process at a time: once a
+    parent has initialized jax on it, a child asking for it fails or
+    hangs at start-up. A child pinned to ``JAX_PLATFORMS=cpu`` (the
+    default) never asks."""
+    if "cpu" == env.get("JAX_PLATFORMS", "").strip().lower():
+        return
+    if "jax" not in sys.modules:
+        return
+    from jax._src import xla_bridge
+
+    if (xla_bridge.backends_are_initialized()
+            and "tpu" in xla_bridge.backends()):
+        raise RuntimeError(
+            "this process holds the TPU, and the member's environment "
+            f"(JAX_PLATFORMS={env.get('JAX_PLATFORMS', '<unset>')!r}) "
+            "asks for it: a chip belongs to one process. Run process/tcp "
+            "members from a parent that has not touched jax, pin them to "
+            "JAX_PLATFORMS=cpu, or serve in-process "
+            "(member_transport='inproc').")
+
+
 def spawn_process_member(model, *, service_id: str, member_kwargs: dict,
                          clock: Callable[[], float] = time.monotonic,
                          transport: str = "unix",
@@ -1052,7 +1075,10 @@ def spawn_process_member(model, *, service_id: str, member_kwargs: dict,
     ``TPU_VISIBLE_CHIPS`` to pin devices per member (jax reads them at
     import, which happens entirely inside the child). With no override
     the child defaults to ``JAX_PLATFORMS=cpu`` — a spawned member must
-    never silently fight its parent for the same accelerator. The
+    never silently fight its parent for the same accelerator. A TPU
+    belongs to one process: when this process holds it and the member's
+    environment asks for it, the spawn raises (``check_chip_free``)
+    instead of starting a child that would hang at start-up. The
     child's persistent compile cache is ``member_kwargs[
     "compile_cache"]`` (default "auto": the shared machine cache, so a
     respawned gen+1 re-uses gen's executables).
@@ -1082,6 +1108,20 @@ def spawn_process_member(model, *, service_id: str, member_kwargs: dict,
         "member_kwargs": _encode_member_kwargs(member_kwargs),
         "pump": pump_mode,
     }
+    env = dict(os.environ)
+    env.setdefault("JAX_PLATFORMS", "cpu")
+    # dtype fidelity across the boundary: the child must read the
+    # wire's f64 state AS f64 — propagate the parent's x64 flag
+    # (overridable through member_env like everything else)
+    try:
+        import jax
+
+        env.setdefault("JAX_ENABLE_X64",
+                       "1" if jax.config.jax_enable_x64 else "0")
+    except (ImportError, AttributeError):  # pragma: no cover
+        pass
+    env.update(member_env or {})
+    check_chip_free(env)
     spawn_dir = tempfile.mkdtemp(prefix=f"mm-member-{service_id}-")
     cfg_path = os.path.join(spawn_dir, "config.json")
     with open(cfg_path, "w") as fh:
@@ -1099,19 +1139,6 @@ def spawn_process_member(model, *, service_id: str, member_kwargs: dict,
         listener.bind(addr)
         listener.listen(1)
     try:
-        env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
-        # dtype fidelity across the boundary: the child must read the
-        # wire's f64 state AS f64 — propagate the parent's x64 flag
-        # (overridable through member_env like everything else)
-        try:
-            import jax
-
-            env.setdefault("JAX_ENABLE_X64",
-                           "1" if jax.config.jax_enable_x64 else "0")
-        except (ImportError, AttributeError):  # pragma: no cover
-            pass
-        env.update(member_env or {})
         if secret is not None:
             env[SECRET_ENV] = secret
         proc = subprocess.Popen(

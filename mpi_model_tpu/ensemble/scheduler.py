@@ -19,8 +19,8 @@ structure)`` — sees a handful of batch shapes instead of one per
 traffic pattern: any load is served with at most ``len(buckets)``
 compiles per structure. The JAX persistent compilation cache rides
 UNDER the runner cache by default (``compile_cache="auto"`` →
-``utils.compile_cache.default_cache_dir()``; pass ``None`` to disable):
-a restarted process re-uses every executable this machine already
+``utils.compile_cache.cache_dir()``; pass ``None`` to disable):
+a restarted process re-uses every executable this checkout already
 built, so cold-start costs one cache read, not one compile, per bucket
 (ROADMAP direction 5).
 
@@ -193,8 +193,7 @@ class EnsembleScheduler:
                  compile_cache: Optional[str] = "auto",
                  service_id: Optional[str] = None,
                  mesh=None):
-        from ..utils.compile_cache import (configure_compile_cache,
-                                           resolve_compile_cache)
+        from ..utils.compile_cache import configure_compile_cache
         from .mesh import resolve_ensemble_mesh
 
         if retry not in ("none", "solo"):
@@ -218,8 +217,7 @@ class EnsembleScheduler:
         self.max_wait_s = float(max_wait_s)
         #: the persistent-cache dir armed under the runner cache
         #: ("auto" default → the machine default; None = disabled)
-        self.compile_cache = configure_compile_cache(
-            resolve_compile_cache(compile_cache))
+        self.compile_cache = configure_compile_cache(compile_cache)
         #: the (batch, space) device mesh every dispatch shards over
         #: (None = single device). Accepts an EnsembleMesh, a batch
         #: extent int, or a (batch, space) pair — the int/pair forms

@@ -88,11 +88,11 @@ class EnsembleService:
     ``compile_cache`` points the JAX persistent compilation cache at a
     directory before the first dispatch compiles. The DEFAULT is
     ``"auto"`` (ISSUE 9 satellite / ROADMAP direction 5): the cache is
-    armed at ``utils.compile_cache.default_cache_dir()`` without being
+    armed at ``utils.compile_cache.cache_dir()`` without being
     asked, so a restarted service re-uses every executable a previous
-    process on this machine already built and reaches full throughput
+    process in this checkout already built and reaches full throughput
     on its first batch. Pass ``None`` to disable, or a directory to
-    pin one.
+    pin one (``$JAX_COMPILATION_CACHE_DIR`` overrides it).
     """
 
     def __init__(self, model, *, steps: Optional[int] = None,

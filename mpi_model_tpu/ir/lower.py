@@ -38,7 +38,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..compat import optimization_barrier
 from ..ops.stencil import (neighbor_counts_traced, shift2d, transport,
                            weighted_counts_traced)
 from .expr import evaluate
@@ -156,7 +155,7 @@ class DenseCtx(_Ctx):
         # contracted), but pinning it keeps the value stable when this
         # same lowering compiles inside other fusion contexts (the
         # active engine's lax.cond fallback, the vmapped ensemble step)
-        outflow = optimization_barrier(rate_c * self.pre[channel])
+        outflow = lax.optimization_barrier(rate_c * self.pre[channel])
         if weights is None:
             self.cur[channel] = transport(self.cur[channel], outflow,
                                           self.counts, self.meta.offsets)
@@ -245,7 +244,7 @@ class WindowCtx(_Ctx):
         win = self.wins[channel]
         th = win.shape[0] - 2
         tw = win.shape[1] - 2
-        outflow = optimization_barrier(rate_c * win)
+        outflow = lax.optimization_barrier(rate_c * win)
         if weights is None:
             counts = self.counts_win
         else:
@@ -299,8 +298,8 @@ def _amount(term, ctx: _Ctx, rate_c):
     except (TypeError, jax.errors.TracerArrayConversionError):
         unit = False  # a traced lane: keep the (exact) multiply
     if unit:
-        return optimization_barrier(amt)
-    return optimization_barrier(optimization_barrier(rate_c) * amt)
+        return lax.optimization_barrier(amt)
+    return lax.optimization_barrier(lax.optimization_barrier(rate_c) * amt)
 
 
 @register_lowering(Transfer)
@@ -355,7 +354,7 @@ def apply_terms(terms: Sequence[Term], ctx: _Ctx,
             if term.budget_channel is not None:
                 wrote.add(term.budget_channel)
             for ch in sorted(wrote):
-                ctx.cur[ch] = optimization_barrier(ctx.cur[ch])
+                ctx.cur[ch] = lax.optimization_barrier(ctx.cur[ch])
     return ctx.cur
 
 
@@ -458,7 +457,7 @@ def maybe_pin(terms, values: dict) -> dict:
     bandwidth-bound bench path where a barrier could cost real ns."""
     if uniform_rates(terms) is not None:
         return values
-    return {k: optimization_barrier(v) for k, v in values.items()}
+    return {k: lax.optimization_barrier(v) for k, v in values.items()}
 
 
 def dense_apply(terms, values: dict, rates, meta: StepMeta,

@@ -42,9 +42,23 @@ class _FlowSpec(ctypes.Structure):
     ]
 
 
+def _sources_newer_than(path: str) -> bool:
+    """True when any native source, header or the CMake file is newer
+    than ``path`` — the gitignored build may predate the checkout."""
+    built = os.path.getmtime(path)
+    roots = [os.path.join(_NATIVE_DIR, d) for d in ("src", "include")]
+    files = [os.path.join(_NATIVE_DIR, "CMakeLists.txt")]
+    for root in roots:
+        for dirpath, _, names in os.walk(root):
+            files += [os.path.join(dirpath, n) for n in names]
+    return any(os.path.getmtime(f) > built for f in files)
+
+
 def build_native(force: bool = False) -> str:
-    """Build libmmtpu.so with cmake+ninja if missing; returns its path."""
-    if os.path.exists(_LIB_PATH) and not force:
+    """Build libmmtpu.so with cmake+ninja when missing or older than its
+    sources; returns its path."""
+    if (os.path.exists(_LIB_PATH) and not force
+            and not _sources_newer_than(_LIB_PATH)):
         return _LIB_PATH
     # analysis: ignore[raw-transport] — a build-tool invocation
     # (cmake), not serving traffic: no fleet bytes cross this edge

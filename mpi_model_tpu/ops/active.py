@@ -229,6 +229,15 @@ def compact_tile_ids(flags: jax.Array,
 
 # -- the per-tile update (bitwise-mirrors ops.stencil.transport) -------------
 
+def vary_like(x: jax.Array, like: jax.Array) -> jax.Array:
+    """``x`` cast to vary over every shard_map mesh axis ``like`` varies
+    over (a no-op outside shard_map): a loop carry or cond branch that
+    starts from a fresh constant and ends holding shard data must carry
+    the same varying-manual-axes type on both ends."""
+    missing = tuple(sorted(jax.typeof(like).vma - jax.typeof(x).vma))
+    return lax.pcast(x, missing, to="varying") if missing else x
+
+
 def active_pass(padded: jax.Array, upd: jax.Array, ids: jax.Array,
                 count: jax.Array, rate, plan: ActivePlan,
                 origin, global_shape: tuple[int, int],
@@ -294,8 +303,9 @@ def active_pass(padded: jax.Array, upd: jax.Array, ids: jax.Array,
         return (lax.dynamic_update_index_in_dim(u, tile_out, l, 0),
                 f.at[l].set(jnp.any(tile_out != 0)))
 
-    anyf = jnp.zeros((plan.capacity,), bool)
-    upd, anyf = lax.fori_loop(0, cmin, compute_body, (upd, anyf))
+    anyf = vary_like(jnp.zeros((plan.capacity,), bool), padded)
+    upd, anyf = lax.fori_loop(0, cmin, compute_body,
+                              (vary_like(upd, padded), anyf))
 
     def scatter_body(l, p):
         r, c = rc_of(ids[l])

@@ -1,6 +1,7 @@
 """Composed k-step stencil filters: break the radius-1 ceiling.
 
-BASELINE.md's round-5 roofline investigation measured ~3.2 ms/step as
+The round-5 roofline investigation (docs/DESIGN.md; measured before PR 1,
+not yet re-measured on this tree) put ~3.2 ms/step as
 the architectural bound for any *radius-1* fused stencil on this chip's
 VPU — ~76% of the slots go to the ±1 slice shifts, work proportional to
 the useful flops — and concluded that beating it "needs a different op
@@ -34,7 +35,7 @@ Two lowerings of the composed filter:
   with precomputed f64 coefficients. Shift-slot count is ~identical to
   k iterated steps — this variant measures whether dropping the
   per-step multiplies and round-trips through the output registers
-  buys anything on the VPU (the slot accounting in BASELINE.md predicts
+  buys anything on the VPU (the round-5 slot accounting predicts
   it cannot, which is half the point: the null must be measured).
 - ``variant="mxu"``: the lane-direction banded contraction, retested at
   the tap counts where round 5 predicted it starts to pay. For each of

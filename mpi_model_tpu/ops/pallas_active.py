@@ -66,7 +66,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..compat import HBM as _HBM, prefetch_scalar_grid_spec
 from ..core.cell import MOORE_OFFSETS
 from .active import (
     ActivePlan,
@@ -77,6 +76,7 @@ from .active import (
     plan_for,
     tile_nonzero_map,
 )
+from .pallas_stencil import resolve_interpret
 from .stencil import neighbor_counts_traced, transport
 
 #: hard cap on the composed pass depth — the window is (th+2k, tw+2k),
@@ -144,10 +144,36 @@ def _fused_compute_call(padded, ids, cnt1, selfnz, origin, *, rate, plan,
     H, W = global_shape
     wh, ww = th + 2 * k, tw + 2 * k
     off = ring - k  # window offset into the (possibly deeper) padding
+    # Mosaic DMAs whole (sublane, lane) tiles of HBM from tile-aligned
+    # offsets: the compiled kernel fetches from the tile's aligned corner
+    # a block rounded up to whole tiles (the extra rows/columns land in
+    # the array's tiled-layout padding past its last tile and are never
+    # read back) and slices the window out of VMEM at ``lead``; the
+    # interpreter fetches the exact window
+    sub = 8 * 4 // jnp.dtype(dtype).itemsize
+    if interpret:
+        lead, fh, fw = 0, wh, ww
+    else:
+        if th % sub or tw % 128:
+            raise ValueError(
+                f"the compiled fused active kernel needs tiles of whole "
+                f"({sub}, 128) layout tiles; got {(th, tw)} — pass "
+                "active_opts tile=... with such divisors")
+        lead = off
+        fh, fw = -(-(off + wh) // sub) * sub, -(-(off + ww) // 128) * 128
     _i32 = np.int32
     tap_list = (None if taps is None else
                 [(dr, dc, float(taps[dr, dc]))
                  for dr in range(2 * k + 1) for dc in range(2 * k + 1)])
+    # the barrier pins the CPU interpreter's fma contraction (see
+    # ``iterated``); Mosaic has no lowering for it
+    pin = lax.optimization_barrier if interpret else (lambda x: x)
+
+    def nonzero(tile):
+        # any(tile != 0) as a max: Mosaic lowers a bool reduce through
+        # the default float type, which is f64 (and refused) under x64
+        return (jnp.max(jnp.abs(tile.astype(jnp.float32))) != 0
+                ).astype(jnp.int32)
 
     def kernel(ids_ref, cnt_ref, self_ref, orig_ref, rate_ref, pad_ref,
                upd_ref, anyf_ref, vwin, sems):
@@ -157,14 +183,17 @@ def _fused_compute_call(padded, ids, cnt1, selfnz, origin, *, rate, plan,
         valid = l < cmax
 
         def rc_of(lane):
+            # the tile's corner in the padded state, ``ring`` above and
+            # left of its interior
             t = ids_ref[lane]
-            return ((t // _i32(gj)) * _i32(th) + _i32(off),
-                    lax.rem(t, _i32(gj)) * _i32(tw) + _i32(off))
+            return ((t // _i32(gj)) * _i32(th),
+                    lax.rem(t, _i32(gj)) * _i32(tw))
 
         def window_copy(lane, sl):
             r, c = rc_of(lane)
             return pltpu.make_async_copy(
-                pad_ref.at[pl.ds(r, wh), pl.ds(c, ww)],
+                pad_ref.at[pl.ds(r + _i32(off - lead), fh),
+                           pl.ds(c + _i32(off - lead), fw)],
                 vwin.at[sl], sems.at[sl])
 
         # double-buffered pipeline (the _stencil_call discipline): lane 0
@@ -182,12 +211,12 @@ def _fused_compute_call(padded, ids, cnt1, selfnz, origin, *, rate, plan,
 
         @pl.when(valid)
         def _():
-            win = vwin[slot]
+            win = vwin[slot, lead:lead + wh, lead:lead + ww]
             r, c = rc_of(l)
-            # global coords of the window's [0, 0] (the padded array's
-            # [off, off] is global [origin - k, origin - k] of the tile)
-            g_r0 = orig_ref[0] + (r - _i32(off)) - _i32(k)
-            g_c0 = orig_ref[1] + (c - _i32(off)) - _i32(k)
+            # global coords of the window's [0, 0]: the tile's interior
+            # starts at global origin + (r, c), and the window k before
+            g_r0 = orig_ref[0] + r - _i32(k)
+            g_c0 = orig_ref[1] + c - _i32(k)
             row_g = g_r0 + lax.broadcasted_iota(jnp.int32, (wh, ww), 0)
             col_g = g_c0 + lax.broadcasted_iota(jnp.int32, (wh, ww), 1)
             in_grid = ((row_g >= 0) & (row_g < H)
@@ -200,7 +229,7 @@ def _fused_compute_call(padded, ids, cnt1, selfnz, origin, *, rate, plan,
             # off-grid window cells can have count 0; their value is 0
             cnt = jnp.maximum(cnt, jnp.asarray(1, win.dtype))
             mask = in_grid.astype(win.dtype)
-            rate_v = rate_ref[0]
+            rate_v = rate_ref[0].astype(win.dtype)
 
             def iterated(cur):
                 # the exact iterated path, mirroring active_pass (and
@@ -218,7 +247,7 @@ def _fused_compute_call(padded, ids, cnt1, selfnz, origin, *, rate, plan,
                 # data and a single step never consumes its own output).
                 for s in range(k):
                     hs, ws = cur.shape
-                    outflow = lax.optimization_barrier(rate_v * cur)
+                    outflow = pin(rate_v * cur)
                     share = outflow / cnt[s:wh - s, s:ww - s]
                     inflow = jnp.zeros((hs - 2, ws - 2), cur.dtype)
                     for dx, dy in offsets:
@@ -234,7 +263,7 @@ def _fused_compute_call(padded, ids, cnt1, selfnz, origin, *, rate, plan,
             if tap_list is None:
                 tile_out = iterated(win)
                 upd_ref[0] = tile_out
-                anyf_ref[0] = jnp.any(tile_out != 0).astype(jnp.int32)
+                anyf_ref[l] = nonzero(tile_out)
             else:
                 # composed-k: the tap table on interior self-lit tiles,
                 # the exact iterated path on near-edge tiles (the
@@ -255,7 +284,7 @@ def _fused_compute_call(padded, ids, cnt1, selfnz, origin, *, rate, plan,
                 def _():
                     tile_out = iterated(win)
                     upd_ref[0] = tile_out
-                    anyf_ref[0] = jnp.any(tile_out != 0).astype(jnp.int32)
+                    anyf_ref[l] = nonzero(tile_out)
 
                 @pl.when(jnp.logical_not(exact))
                 def _():
@@ -264,7 +293,7 @@ def _fused_compute_call(padded, ids, cnt1, selfnz, origin, *, rate, plan,
                         acc = acc + jnp.asarray(tap, dtype=win.dtype) * win[
                             dr:dr + th, dc:dc + tw]
                     upd_ref[0] = acc
-                    anyf_ref[0] = jnp.any(acc != 0).astype(jnp.int32)
+                    anyf_ref[l] = nonzero(acc)
 
         @pl.when(jnp.logical_not(valid))
         def _():
@@ -273,23 +302,31 @@ def _fused_compute_call(padded, ids, cnt1, selfnz, origin, *, rate, plan,
             # computes tile 0's identically-zero update, so the scatter
             # pass never flushes an unwritten VMEM block)
             upd_ref[0] = jnp.zeros((th, tw), upd_ref.dtype)
-            anyf_ref[0] = jnp.zeros((), jnp.int32)
+            anyf_ref[l] = jnp.zeros((), jnp.int32)
 
-    grid_spec = prefetch_scalar_grid_spec(
+    grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(K,),
-        in_specs=[pl.BlockSpec(memory_space=_HBM)],
+        in_specs=[pl.BlockSpec(memory_space=pltpu.HBM)],
         out_specs=[
             pl.BlockSpec((1, th, tw),
-                         lambda l, i, c, s, o, rt: (l, 0, 0)),
-            pl.BlockSpec((1,), lambda l, i, c, s, o, rt: (l,)),
+                         lambda l, i, c, s, o, rt: (l, _i32(0), _i32(0))),
+            # the [K] flags stay resident in SMEM across the grid: a
+            # (1,)-block of a rank-1 VMEM array is below Mosaic's
+            # 128-lane tiling (the explicit int32 index map keeps x64
+            # from typing the default one i64)
+            pl.BlockSpec((K,), lambda l, i, c, s, o, rt: (_i32(0),),
+                         memory_space=pltpu.SMEM),
         ],
         scratch_shapes=[
-            pltpu.VMEM((2, wh, ww), jnp.dtype(dtype)),
+            pltpu.VMEM((2, fh, fw), jnp.dtype(dtype)),
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
-    rate1 = jnp.reshape(jnp.asarray(rate, dtype=jnp.dtype(dtype)), (1,))
+    # SMEM holds 32-bit scalars: a bf16 rate rides as f32 and is cast
+    # in the kernel (f64 only ever runs interpreted)
+    rate_dt = jnp.promote_types(dtype, jnp.float32)
+    rate1 = jnp.reshape(jnp.asarray(rate, dtype=rate_dt), (1,))
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -313,32 +350,53 @@ def _fused_scatter_call(padded, upd, ids, cnt1, *, plan, ring, interpret):
     (th, tw), (gi, gj) = plan.tile, plan.grid
     K = plan.capacity
     _i32 = np.int32
+    # Mosaic DMAs whole (sublane, lane) tiles of HBM, and a tile's
+    # interior sits ``ring`` cells off the tile grid. The compiled kernel
+    # therefore read-modify-writes the tile-aligned region holding it
+    # (lanes run in order, each DMA completes before the next lane's, so
+    # a region shared with a neighbour lane carries that lane's write
+    # forward); the interpreter writes the interior directly.
+    sub = 8 * 4 // jnp.dtype(padded.dtype).itemsize
+    rh, rw = -(-(th + ring) // sub) * sub, -(-(tw + ring) // 128) * 128
 
-    def kernel(ids_ref, cnt_ref, upd_ref, pad_in_ref, out_ref, sem):
+    def kernel(ids_ref, cnt_ref, upd_ref, pad_in_ref, out_ref, buf, sem):
         l = pl.program_id(0)
         cmax = jnp.clip(cnt_ref[0], _i32(1), _i32(K))
 
         @pl.when(l < cmax)
         def _():
             t = ids_ref[l]
-            r = (t // _i32(gj)) * _i32(th) + _i32(ring)
-            c = lax.rem(t, _i32(gj)) * _i32(tw) + _i32(ring)
-            cp = pltpu.make_async_copy(
-                upd_ref.at[0],
-                out_ref.at[pl.ds(r, th), pl.ds(c, tw)],
-                sem)
+            r = (t // _i32(gj)) * _i32(th)
+            c = lax.rem(t, _i32(gj)) * _i32(tw)
+            if interpret:
+                cp = pltpu.make_async_copy(
+                    upd_ref.at[0],
+                    out_ref.at[pl.ds(r + _i32(ring), th),
+                               pl.ds(c + _i32(ring), tw)],
+                    sem)
+                cp.start()
+                cp.wait()
+                return
+            region = out_ref.at[pl.ds(r, rh), pl.ds(c, rw)]
+            cp = pltpu.make_async_copy(region, buf, sem)
+            cp.start()
+            cp.wait()
+            buf[pl.ds(ring, th), pl.ds(ring, tw)] = upd_ref[0]
+            cp = pltpu.make_async_copy(buf, region, sem)
             cp.start()
             cp.wait()
 
-    grid_spec = prefetch_scalar_grid_spec(
+    grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(K,),
         in_specs=[
-            pl.BlockSpec((1, th, tw), lambda l, i, c: (l, 0, 0)),
-            pl.BlockSpec(memory_space=_HBM),
+            pl.BlockSpec((1, th, tw),
+                         lambda l, i, c: (l, _i32(0), _i32(0))),
+            pl.BlockSpec(memory_space=pltpu.HBM),
         ],
-        out_specs=pl.BlockSpec(memory_space=_HBM),
-        scratch_shapes=[pltpu.SemaphoreType.DMA(())],
+        out_specs=pl.BlockSpec(memory_space=pltpu.HBM),
+        scratch_shapes=[pltpu.VMEM((rh, rw), padded.dtype),
+                        pltpu.SemaphoreType.DMA(())],
     )
     return pl.pallas_call(
         kernel,
@@ -355,7 +413,7 @@ def fused_active_pass(padded, ids, count, selfnz, rate, plan: ActivePlan,
                       offsets: Sequence[tuple[int, int]], dtype,
                       k: int = 1, ring: Optional[int] = None,
                       taps: Optional[np.ndarray] = None,
-                      interpret: bool = True):
+                      interpret: Optional[bool] = None):
     """One fused pass over the compacted active set: ``k`` flow steps
     per tile-resident window, flags computed in-kernel. Returns
     ``(padded', anyf)`` where ``anyf`` is the ``[K]`` bool per-lane
@@ -369,9 +427,13 @@ def fused_active_pass(padded, ids, count, selfnz, rate, plan: ActivePlan,
     grid; the shard offset under sharding). ``selfnz`` is the ``[K]``
     pre-pass self-tile-nonzero gather (``int32``; only consulted when a
     tap table is armed — frontier tiles keep the exact iterated path).
+    ``interpret`` None resolves from where ``padded`` lives
+    (``resolve_interpret``).
     """
     if ring is None:
         ring = k
+    if interpret is None:
+        interpret = resolve_interpret(padded)
     if k < 1 or k > min(plan.tile):
         raise ValueError(
             f"fused pass depth k={k} must be in [1, min(tile)="
@@ -416,7 +478,7 @@ def build_fused_runner(shape: tuple[int, int], rates: dict,
                        dense_fns: Optional[dict] = None,
                        traced_rates: bool = False,
                        track_dirty: bool = False,
-                       interpret: bool = True) -> Callable:
+                       interpret: Optional[bool] = None) -> Callable:
     """Whole-run fused active stepper — ``ops.active.build_active_runner``
     with the gather/compute/flags replaced by the fused Pallas pass:
     ``run(values, n[, rates_vec]) -> (values, stats)`` where ``stats`` is
@@ -587,7 +649,7 @@ class FusedActiveStep:
                  max_active_frac: float = 0.25,
                  k: int = 1, passes: int = 1,
                  dense_fn: Optional[Callable] = None,
-                 interpret: bool = True):
+                 interpret: Optional[bool] = None):
         self.shape = tuple(shape)
         self.rate = float(rate)
         self.dtype = jnp.dtype(dtype)
@@ -599,7 +661,7 @@ class FusedActiveStep:
                              max_active_frac=max_active_frac)
         self.k = int(k)
         self.passes = int(passes)
-        self.interpret = bool(interpret)
+        self.interpret = interpret
         if self.k < 1 or self.k > min(min(self.plan.tile), MAX_FUSED_K):
             raise ValueError(
                 f"k={k} outside [1, min(min(tile), {MAX_FUSED_K})] for "

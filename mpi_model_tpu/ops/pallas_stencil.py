@@ -48,7 +48,6 @@ import numpy as np
 from jax import lax
 
 from ..core.cell import MOORE_OFFSETS
-from ..compat import HBM as _HBM, tpu_compiler_params
 
 LANE = 128  # TPU lane tile (last dim)
 
@@ -512,7 +511,7 @@ def _stencil_call(v, halo_operands, *, rate, block, offsets, interpret,
         # pinned to HBM: DMA offsets into HBM are unconstrained, and
         # ANY would let the compiler pick VMEM for small grids,
         # re-imposing the (SUB, LANE) slice alignment on the source
-        pl.BlockSpec(memory_space=_HBM),
+        pl.BlockSpec(memory_space=pltpu.HBM),
     ]
     if halo:
         nslab, sslab, wfull, efull, origin = halo_operands
@@ -520,7 +519,7 @@ def _stencil_call(v, halo_operands, *, rate, block, offsets, interpret,
         # the SMEM spec needs an EXPLICIT int32 index map: the default
         # one returns literal zeros, which trace to i64 under
         # jax_enable_x64 and fail Mosaic verification (func.return i64)
-        in_specs = ([pl.BlockSpec(memory_space=_HBM)] * 5
+        in_specs = ([pl.BlockSpec(memory_space=pltpu.HBM)] * 5
                     + [pl.BlockSpec((2,), lambda i, j: (np.int32(0),),
                                     memory_space=pltpu.SMEM)])
     return pl.pallas_call(
@@ -536,7 +535,7 @@ def _stencil_call(v, halo_operands, *, rate, block, offsets, interpret,
         # double-buffered windows + f32 temporaries overflow the default
         # 16MB scoped-VMEM budget at the fastest block sizes; v5e has
         # 128MB physical VMEM
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
     )(*operands)
@@ -731,7 +730,7 @@ def _pipeline_call(v, *, rate, block, offsets, interpret, nsteps,
         in_specs=specs,
         out_specs=pl.BlockSpec((bh, bw), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((h, w), v.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=110 * 1024 * 1024),
         interpret=interpret,
     )(*([v] * 9))
@@ -861,11 +860,11 @@ def mesh_interpret(mesh) -> bool:
 
     Inside ``shard_map`` every value is a tracer, so sample-based
     resolution falls through to ambient config — which can disagree with
-    the mesh both ways (round-3 VERDICT weak #1: a CPU mesh under a
-    force-registered TPU backend crashed with "Only interpret mode is
-    supported on CPU backend"; a TPU mesh under a CPU default device
-    would silently run the kernel interpreted — a perf cliff). The mesh
-    IS the execution placement; resolve from it."""
+    the mesh both ways (a CPU mesh in a process whose default backend is
+    a TPU would crash with "Only interpret mode is supported on CPU
+    backend"; a TPU mesh under a CPU default device would silently run
+    the kernel interpreted — a perf cliff). The mesh IS the execution
+    placement; resolve from it."""
     return mesh.devices.flat[0].platform == "cpu"
 
 
@@ -938,7 +937,7 @@ def pallas_dense_step(
     buffer) but LOSES ~1.45x under the production chained scan, where
     each step reads the buffer the previous step just wrote — measured
     both ways at 16384² bf16 x4 with interleaved medians (round-5
-    roofline investigation, BASELINE.md). The ensemble engine surfaces
+    roofline investigation, docs/DESIGN.md). The ensemble engine surfaces
     it as its opt-in interior engine
     (``ensemble.EnsembleExecutor(impl="pipeline")``: one dispatch per
     scenario lane under ``lax.map`` — back-to-back dispatches read
@@ -1369,12 +1368,12 @@ def _field_call(chans, names, flows, *, block, offsets, interpret, nsteps,
             write_out(cur)
 
     operands = list(chans)
-    in_specs = [pl.BlockSpec(memory_space=_HBM)] * C
+    in_specs = [pl.BlockSpec(memory_space=pltpu.HBM)] * C
     if halo:
         slabs, origin = halo_operands
         operands += list(slabs) + [origin]
         # explicit int32 index map for SMEM (see _stencil_call)
-        in_specs += ([pl.BlockSpec(memory_space=_HBM)] * (4 * C)
+        in_specs += ([pl.BlockSpec(memory_space=pltpu.HBM)] * (4 * C)
                      + [pl.BlockSpec((2,), lambda i, j: (np.int32(0),),
                                      memory_space=pltpu.SMEM)])
     return pl.pallas_call(
@@ -1387,7 +1386,7 @@ def _field_call(chans, names, flows, *, block, offsets, interpret, nsteps,
             pltpu.VMEM((C, 2, wh, ww), dtype),
             pltpu.SemaphoreType.DMA((2, C, n_pieces)),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=96 * 1024 * 1024),
         interpret=interpret,
     )(*operands)

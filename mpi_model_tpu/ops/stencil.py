@@ -68,8 +68,8 @@ def neighbor_counts_traced(
 
     The numpy twin (``core.cell.neighbor_count_grid``) materializes a
     concrete array — closing a jitted step over that bakes an O(grid)
-    constant into the compiled program (256MB at 8192² f32, which also
-    overflows remote-compile transports). Recomputing from iotas inside
+    constant into the compiled program (256MB at 8192² f32). Recomputing
+    from iotas inside
     the step is a handful of VPU compares per cell — cheaper than the
     HBM read of a materialized counts array in a bandwidth-bound step.
     """

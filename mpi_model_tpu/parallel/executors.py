@@ -34,16 +34,15 @@ count), so supervisor chunks of any size — including the remainder chunk
 
 from __future__ import annotations
 
-import warnings
 from functools import partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
 from ..core.cellular_space import CellularSpace
 from ..ops.flow import PointFlow
 from ..resilience import inject
@@ -163,7 +162,7 @@ class ShardMapExecutor:
     ``"active_fused"`` (shard-local active-tile stepping — the XLA
     engine or the fused Pallas kernel over the same ghost-padded
     windows; ``_build_active_runner``), or ``"auto"`` (pallas when
-    eligible and its compile succeeds, else xla).
+    eligible, else xla; a Pallas failure propagates).
     """
 
     def __init__(self, mesh: Mesh, step_impl: str = "xla",
@@ -206,7 +205,7 @@ class ShardMapExecutor:
         #: shard step ignores it
         self.compute_dtype = compute_dtype
         #: kernel the last ``run_model`` actually used ("pallas"/"xla"),
-        #: after any "auto" fallback — reported by the CLI/bench
+        #: what "auto" resolved to — reported by the CLI/bench
         self.last_impl: Optional[str] = None
         #: per-run report detail (Report.backend_report); None until a
         #: run records one
@@ -460,19 +459,17 @@ class ShardMapExecutor:
                 self.last_backend_report["flags_fused"] = int(ff)
             return out
 
-        # one probe/build/cache protocol for both depths: the fused
-        # Pallas kernel is tried first (deep halos compose with it — a
-        # depth-d ring feeds d fused steps per exchange: one collective
-        # round AND one HBM round-trip per d steps), else the XLA
-        # shard step (deep or pad-gather) is built
+        # one build/cache protocol for both depths: the fused Pallas
+        # kernel when eligible (deep halos compose with it — a depth-d
+        # ring feeds d fused steps per exchange: one collective round
+        # AND one HBM round-trip per d steps), else the XLA shard step
+        # (deep or pad-gather)
         deep = self.halo_depth > 1
         entry = self._cache.get(key)
         if entry is None:
-            kind, prunner, out = self._probe_pallas(
+            kind, prunner, out = self._run_pallas(
                 model, space, num_steps, values,
-                label="pallas-deep" if deep else "pallas",
-                fallback_name=("the XLA deep-halo path" if deep
-                               else "the XLA pad-gather path"))
+                label="pallas-deep" if deep else "pallas")
             if prunner is not None:
                 self._cache[key] = (kind, prunner)
                 self.last_impl = kind
@@ -486,9 +483,9 @@ class ShardMapExecutor:
             entry = ("xla", runner)
             self._cache[key] = entry
         kind, runner = entry
-        #: the kernel the last run actually used (after any "auto"
-        #: fallback) — the CLI/bench report it so a user never believes
-        #: they measured a configuration that never ran
+        #: the kernel the last run actually used (what "auto" resolved
+        #: to) — the CLI/bench report it so a user never believes they
+        #: measured a configuration that never ran
         self.last_impl = kind
         self._record_backend_report(kind, num_steps)
         return runner(values, n)
@@ -509,18 +506,14 @@ class ShardMapExecutor:
             "remainder_chunk_depth": num_steps % d,
         }
 
-    def _probe_pallas(self, model, space, num_steps, values, *, label,
-                      fallback_name):
-        """Build + first-run the Pallas runner under one guard (BUILD-time
-        validation errors — e.g. a ring deeper than the slab capacity —
-        and compile/device faults degrade identically). Returns
-        ``(kind, runner, first_output)`` on success — ``kind`` is the
-        honest ``last_impl`` label ("pallas" or "composed") —
-        ``(None, None, None)`` when ineligible or when ``"auto"`` should
-        fall back; re-raises under explicit ``step_impl="pallas"`` /
-        ``"composed"``. ``block_until_ready`` makes async device faults
-        surface HERE, not in the caller after a broken runner got
-        cached."""
+    def _run_pallas(self, model, space, num_steps, values, *, label):
+        """Build + first-run the Pallas runner when the model is
+        eligible. Returns ``(kind, runner, first_output)`` — ``kind`` is
+        the honest ``last_impl`` label ("pallas" or "composed") — or
+        ``(None, None, None)`` when ineligible. A build, compile or
+        device failure propagates, under ``"auto"`` too.
+        ``block_until_ready`` makes async device faults surface HERE,
+        not in the caller after a broken runner got cached."""
         from ..utils.tracing import get_tracer
 
         plan = self._pallas_plan(model, space)
@@ -530,24 +523,12 @@ class ShardMapExecutor:
         if kind == "composed":
             label = f"composed-depth{self.halo_depth}"
         tracer = get_tracer()
-        try:
-            with tracer.span("shardmap.build", impl=label,
-                             depth=self.halo_depth):
-                prunner = self._build_pallas_runner(model, space, plan)
-            with tracer.span("shardmap.compile+first_run", impl=label):
-                out = jax.block_until_ready(
-                    prunner(values, jnp.int32(num_steps)))
-        # analysis: ignore[broad-except] — compile-probe boundary: the
-        # sharded pallas/composed build+first-run may fail with any
-        # Mosaic/XLA/device error; explicit impls re-raise, auto falls
-        # back to the XLA shard step
-        except Exception as e:
-            if self.step_impl in ("pallas", "composed"):
-                raise
-            warnings.warn(
-                f"{label} step failed ({e!r}); falling back to "
-                f"{fallback_name}", RuntimeWarning)
-            return None, None, None
+        with tracer.span("shardmap.build", impl=label,
+                         depth=self.halo_depth):
+            prunner = self._build_pallas_runner(model, space, plan)
+        with tracer.span("shardmap.compile+first_run", impl=label):
+            out = jax.block_until_ready(
+                prunner(values, jnp.int32(num_steps)))
         return kind, prunner, out
 
     def _shard_geometry(self, space: CellularSpace):
@@ -1064,10 +1045,15 @@ class ShardMapExecutor:
                     ff = ff + fs
                 return new_v, new_t, new_u, fb, at, ff
 
+            # the update buffers and counters end each step varying over
+            # the mesh, so they start that way
+            ref = next(iter(values.values()))
+            upd0, fb0, at0, ff0 = jax.tree.map(
+                lambda x: act.vary_like(x, ref),
+                (upd0, jnp.int32(0), jnp.float32(0), jnp.int32(0)))
             # n is a TRACED scalar: one compile serves every step count
             out, _, _, fb, at, ff = lax.fori_loop(
-                0, n, body, (values, tmap0, upd0, jnp.int32(0),
-                             jnp.float32(0), jnp.int32(0)))
+                0, n, body, (values, tmap0, upd0, fb0, at0, ff0))
             # one collective for all counters (psum over the tuple)
             fb, at, ff = lax.psum((fb, at, ff), names)
             if fused:
@@ -1077,7 +1063,7 @@ class ShardMapExecutor:
         stat_spec = (P(), P(), P()) if fused else (P(), P())
         sharded = shard_map(shard_fn, mesh=mesh, in_specs=(spec, P()),
                             out_specs=(spec, stat_spec),
-                            check_vma=False if fused else None)
+                            check_vma=not fused)
         return jax.jit(sharded), plan, len(live), nx * ny
 
     def _build_ir_runner(self, model, space: CellularSpace):

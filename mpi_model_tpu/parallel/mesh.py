@@ -27,8 +27,7 @@ from ..core.cellular_space import CellularSpace
 def _devices(devices=None):
     if devices is not None:
         return list(devices)
-    # Honor an explicitly pinned default device (e.g. tests pin "cpu" while
-    # the image force-registers a TPU backend).
+    # Honor an explicitly pinned default device (e.g. tests pin "cpu").
     dd = jax.config.jax_default_device
     if dd is not None:
         platform = dd if isinstance(dd, str) else dd.platform

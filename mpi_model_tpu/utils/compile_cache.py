@@ -1,20 +1,24 @@
-"""Persistent JAX compilation cache (cold-start elimination, slice 1 of
-ROADMAP direction 5).
+"""Persistent JAX compilation cache, placed by one rule.
 
-Every process restart recompiles every kernel — for the fused active
-path (ISSUE 8) that is two Pallas kernels per (plan, k, dtype) plus the
-runner loops, seconds each on a laptop and worse over a remote-compile
-tunnel. The JAX persistent compilation cache keys compiled executables
-by (HLO, jaxlib version, flags, device kind) and serves them across
-processes, so a machine pays each compile ONCE — a restarted service
-reaches full throughput on its first batch.
+Every process restart recompiles every kernel — seconds each for the
+Pallas kernels and the runner loops. The JAX persistent compilation
+cache keys compiled executables by (HLO, jaxlib version, flags, device
+kind) and serves them across processes, so a machine pays each compile
+ONCE — a restarted service reaches full throughput on its first batch.
+
+The placement rule (``cache_dir``): ``$JAX_COMPILATION_CACHE_DIR`` when
+it is set — jax reads it itself and no directory is set in code, so a
+``--compile-cache DIR`` request gives way to it; otherwise the requested
+directory, or ``<checkout>/.jax_cache`` (gitignored) for ``"auto"``. A
+fixed path inside the checkout keeps the cache's key stable across runs
+and writes nothing outside the checkout.
 
 ``configure_compile_cache(dir)`` is the ONE place the knobs are set;
 the CLI's ``--compile-cache DIR`` flag, ``EnsembleService(
-compile_cache=...)`` and ``bench.enable_compile_cache`` all route here.
-The bar for entry is dropped to zero compile seconds / any entry size —
-on the CPU test rigs even the tiny kernels should populate, which is
-what the cross-process test asserts.
+compile_cache=...)``, ``bench.enable_compile_cache`` and
+``chip_smoke.py`` all route here. The bar for entry is dropped to zero
+compile seconds / any entry size — on the CPU test rigs even the tiny
+kernels should populate, which is what the cross-process test asserts.
 """
 
 from __future__ import annotations
@@ -22,63 +26,61 @@ from __future__ import annotations
 import os
 from typing import Optional
 
-#: config knobs to apply: (name, value). Applied best-effort in order —
-#: an older jax missing a knob keeps the cache as a plain optimization.
+#: config knobs to apply: (name, value)
 _KNOBS = (
     ("jax_persistent_cache_min_compile_time_secs", 0),
     ("jax_persistent_cache_min_entry_size_bytes", -1),
 )
 
+ENV = "JAX_COMPILATION_CACHE_DIR"
+
+#: the checkout-local default (listed in .gitignore)
+CHECKOUT_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
 _configured: Optional[str] = None
 
 
-def configure_compile_cache(cache_dir: Optional[str]) -> Optional[str]:
-    """Point the JAX persistent compilation cache at ``cache_dir``
-    (created if missing) and lower the entry bars so every compile is
-    cached. Returns the directory actually configured, or None when
-    ``cache_dir`` is None/empty (explicitly disabled — the caller's
-    flag was not set) or the running jax has no cache support.
+def cache_dir(requested: Optional[str] = None) -> str:
+    """Where the cache lives: ``$JAX_COMPILATION_CACHE_DIR`` when set,
+    else ``requested``, else ``<checkout>/.jax_cache``."""
+    return os.environ.get(ENV) or requested or CHECKOUT_CACHE
 
-    Idempotent: reconfiguring with the same directory is a no-op;
-    a DIFFERENT directory re-points the cache (jax allows updating the
+
+def configure_compile_cache(spec: Optional[str]) -> Optional[str]:
+    """Arm the JAX persistent compilation cache at
+    ``resolve_compile_cache(spec)`` and lower the entry bars so every
+    compile is cached. Returns the directory in use, or None when
+    ``spec`` is None/empty (explicitly disabled).
+
+    Idempotent: reconfiguring with the same directory is a no-op; a
+    DIFFERENT directory re-points the cache (jax allows updating the
     config between compiles)."""
     global _configured
-    if not cache_dir:
+    target = resolve_compile_cache(spec)
+    if target is None:
         return None
-    cache_dir = os.path.abspath(os.path.expanduser(str(cache_dir)))
-    if _configured == cache_dir:
-        return cache_dir
     import jax
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-    except (AttributeError, KeyError, ValueError, OSError) as e:
-        # the cache is an optimization, never a hard failure — but the
-        # caller ASKED for it, so a dir that can't be armed must warn
-        # (the CLI's errors-not-silent-no-ops rule), not vanish
-        import warnings
-        warnings.warn(
-            f"persistent compile cache at {cache_dir!r} could not be "
-            f"armed ({type(e).__name__}: {e}); every compile will be "
-            "paid per process", RuntimeWarning)
-        return None
+
+    env = os.environ.get(ENV)
+    target = os.path.abspath(os.path.expanduser(target))
+    if _configured == target:
+        return target
+    os.makedirs(target, exist_ok=True)
+    if not env:
+        jax.config.update("jax_compilation_cache_dir", target)
     for name, value in _KNOBS:
-        try:
-            jax.config.update(name, value)
-        except (AttributeError, KeyError, ValueError):
-            pass  # older jax without this knob
+        jax.config.update(name, value)
     # jax memoizes its cache-used decision at the FIRST compile of the
     # process; a process that compiled anything before this call (test
     # rigs, library embedders) would silently keep the cache off —
     # reset so the next compile re-initializes against the new dir
-    try:
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _cc)
-        _cc.reset_cache()
-    except (ImportError, AttributeError):
-        pass  # older jax: the dir config alone armed it
-    _configured = cache_dir
-    return cache_dir
+    from jax.experimental.compilation_cache import compilation_cache as _cc
+
+    _cc.reset_cache()
+    _configured = target
+    return target
 
 
 def configured_dir() -> Optional[str]:
@@ -87,29 +89,12 @@ def configured_dir() -> Optional[str]:
     return _configured
 
 
-def default_cache_dir() -> str:
-    """The default persistent-cache directory for ``"auto"``:
-    ``$JAX_COMPILATION_CACHE_DIR`` when set, else a PER-USER cache path
-    (``$XDG_CACHE_HOME``/``~/.cache`` + ``mpi_model_tpu/jax_cache``).
-    Deliberately NOT a world-shared tempdir: the cache deserializes and
-    executes compiled artifacts, and a predictable shared path would
-    let another local user pre-plant entries (or simply own the
-    directory so ours fails to arm) — the bench's opt-in ``/tmp``
-    default is its own, explicit, choice."""
-    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if env:
-        return env
-    base = (os.environ.get("XDG_CACHE_HOME")
-            or os.path.join(os.path.expanduser("~"), ".cache"))
-    return os.path.join(base, "mpi_model_tpu", "jax_cache")
-
-
 def resolve_compile_cache(spec) -> Optional[str]:
     """Map a ``compile_cache`` knob value to a directory: ``"auto"`` →
-    ``default_cache_dir()`` (the ISSUE 9 satellite — the persistent
-    cache rides under the scheduler's runner cache BY DEFAULT, so a
-    restarted service reaches full throughput on its first batch);
-    ``None``/empty → disabled; any other string → that directory."""
-    if spec == "auto":
-        return default_cache_dir()
-    return spec or None
+    ``cache_dir()`` (the persistent cache rides under the scheduler's
+    runner cache BY DEFAULT, so a restarted service reaches full
+    throughput on its first batch); ``None``/empty → disabled; any
+    other string → ``cache_dir(that)``."""
+    if not spec:
+        return None
+    return cache_dir(None if spec == "auto" else spec)

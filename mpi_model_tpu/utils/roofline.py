@@ -1,16 +1,16 @@
 """Roofline accounting: how close a measured step runs to chip ceilings.
 
-Round-3 VERDICT missing #4: BENCH reported cell-updates/s but never the
-achieved fraction of peak, so "84× the north star" could still be far
-from this chip's roofline and nobody could tell from the artifacts.
-Since the reference publishes nothing (``/root/reference/README.md:1``),
-we own the baseline AND its ceiling analysis (SURVEY §6).
+Cell-updates/s alone cannot say how far a kernel sits from the chip's
+roofline; since the reference publishes nothing
+(``/root/reference/README.md:1``), we own the baseline AND its ceiling
+analysis (SURVEY §6).
 
 Peaks are parameterized per ``device_kind`` from public datasheet
-numbers; the VPU figure is an ESTIMATE (vector-unit throughput is not
-published the way MXU TFLOPs are: lanes × ALU slots × clock). Override
-with env vars when better numbers are known for a given part:
-``MMTPU_HBM_PEAK_GBPS``, ``MMTPU_VPU_PEAK_GOPS``.
+numbers (Google Cloud TPU documentation). The VPU figure is an ESTIMATE
+(vector-unit throughput is not published the way MXU TFLOPs are:
+lanes × ALU slots × clock), and its table key says so. A share of peak
+is only ever computed for a chip in the table: an unknown
+``device_kind`` is an error there, never a default.
 
 The stencil model (``stencil_roofline``) charges the fused kernel
 2·bytes/cell of HBM traffic per ``substeps``-step chunk (one read + one
@@ -19,30 +19,30 @@ and ``flops_per_cell`` VPU ops per cell per step — 11 for the Moore-8
 closed-form interior (1 mul rate·v, 1 div-by-count folded to a mul,
 7 adds for the 8-share sum, 2 update adds), counting every add/mul as
 one op. These are *useful-arithmetic* floors: the kernel also spends
-VPU slots on the shifted-window data movement, so pct_of_compute_peak
+VPU slots on the shifted-window data movement, so pct_of_vpu_estimate
 understates true VPU occupancy.
 """
 
 from __future__ import annotations
 
-import os
 from typing import Any, Optional
 
-#: public-datasheet peaks per jax ``device_kind`` (VPU = estimate, see
+#: public-datasheet peaks per jax ``device_kind`` (Google Cloud TPU
+#: documentation; ``vpu_gops_estimate`` is NOT published — see the
 #: module docstring). hbm in GB/s; vpu in Gop/s for f32 elementwise.
 CHIP_PEAKS: dict[str, dict[str, float]] = {
     # v5e: 819 GB/s HBM, 197 bf16 MXU TFLOPs; VPU ≈ 8·128 lanes ×
     # 4 ALU slots × ~0.94 GHz ≈ 3.9 Top/s
-    "TPU v5 lite": {"hbm_gbps": 819.0, "vpu_gops": 3900.0,
+    "TPU v5 lite": {"hbm_gbps": 819.0, "vpu_gops_estimate": 3900.0,
                     "mxu_bf16_tflops": 197.0},
     # v4: 1228 GB/s, 275 bf16 TFLOPs
-    "TPU v4": {"hbm_gbps": 1228.0, "vpu_gops": 4300.0,
+    "TPU v4": {"hbm_gbps": 1228.0, "vpu_gops_estimate": 4300.0,
                "mxu_bf16_tflops": 275.0},
     # v5p: 2765 GB/s, 459 bf16 TFLOPs
-    "TPU v5": {"hbm_gbps": 2765.0, "vpu_gops": 7000.0,
+    "TPU v5": {"hbm_gbps": 2765.0, "vpu_gops_estimate": 7000.0,
                "mxu_bf16_tflops": 459.0},
     # v6e (Trillium): 1640 GB/s, 918 bf16 TFLOPs
-    "TPU v6 lite": {"hbm_gbps": 1640.0, "vpu_gops": 7800.0,
+    "TPU v6 lite": {"hbm_gbps": 1640.0, "vpu_gops_estimate": 7800.0,
                     "mxu_bf16_tflops": 918.0},
 }
 
@@ -63,9 +63,7 @@ def _lookup_peaks(kind: str) -> dict[str, float]:
     """Exact match, then the alias table, then the longest table key
     that prefixes the reported ``device_kind`` AT A WORD BOUNDARY
     ('TPU v4 pod slice' → 'TPU v4'; 'TPU v4i' does NOT match — a letter
-    suffix is a different chip). An unmatched TPU part warns once
-    instead of silently losing its percent-of-peak (round-4 ADVICE);
-    inventing the wrong ceiling would be worse than omitting it."""
+    suffix is a different chip); ``{}`` for a kind not in the table."""
     k = " ".join(kind.split())
     if k in CHIP_PEAKS:
         return dict(CHIP_PEAKS[k])
@@ -74,39 +72,19 @@ def _lookup_peaks(kind: str) -> dict[str, float]:
     for key in sorted(CHIP_PEAKS, key=len, reverse=True):
         if k.startswith(key + " "):
             return dict(CHIP_PEAKS[key])
-    if "tpu" in k.lower() and k not in _WARNED_KINDS:
-        import warnings
-
-        _WARNED_KINDS.add(k)
-        warnings.warn(
-            f"unrecognized TPU device_kind {kind!r}: no peak table entry "
-            f"(known: {sorted(CHIP_PEAKS)}); percent-of-peak will be "
-            f"omitted — set MMTPU_HBM_PEAK_GBPS / MMTPU_VPU_PEAK_GOPS "
-            f"to supply peaks", stacklevel=3)
     return {}
 
 
-_WARNED_KINDS: set[str] = set()
-
-
 def chip_peaks(device=None) -> Optional[dict[str, Any]]:
-    """Peak table entry for ``device`` (default: first jax device), with
-    env overrides applied; None for unknown parts (e.g. CPU test rigs —
-    report measurements without percent-of-peak rather than invent a
-    ceiling)."""
+    """Peak table entry for ``device`` (default: first jax device); None
+    for a kind the table does not hold (CPU rigs included)."""
     import jax
 
     if device is None:
         device = jax.devices()[0]
     kind = getattr(device, "device_kind", "")
     peaks = _lookup_peaks(kind)
-    hbm = os.environ.get("MMTPU_HBM_PEAK_GBPS")
-    vpu = os.environ.get("MMTPU_VPU_PEAK_GOPS")
-    if hbm:
-        peaks["hbm_gbps"] = float(hbm)
-    if vpu:
-        peaks["vpu_gops"] = float(vpu)
-    if not peaks.get("hbm_gbps"):
+    if not peaks:
         return None
     peaks["device_kind"] = kind
     return peaks
@@ -116,8 +94,9 @@ def stencil_roofline(grid: int, itemsize: int, t_step_s: float,
                      substeps: int = 1, nchannels: int = 1,
                      flops_per_cell: float = 11.0,
                      device=None) -> dict[str, Any]:
-    """Achieved bandwidth/throughput (and % of peak when the chip is
-    known) for one measured stencil step of ``t_step_s`` seconds.
+    """Achieved bandwidth/throughput and share of peak for one measured
+    stencil step of ``t_step_s`` seconds. Raises ``ValueError`` for a
+    device whose ``device_kind`` the peak table does not hold.
 
     ``t_step_s`` is the per-FLOW-step time; with ``substeps``-fused
     kernels the HBM traffic amortizes over the chunk, the arithmetic
@@ -130,16 +109,17 @@ def stencil_roofline(grid: int, itemsize: int, t_step_s: float,
         "flops_per_step": flops_per_step,
         "achieved_gbps": bytes_per_step / t_step_s / 1e9,
         "achieved_gflops": flops_per_step / t_step_s / 1e9,
-        "pct_of_hbm_peak": None,
-        "pct_of_compute_peak": None,
-        "device_kind": None,
     }
     peaks = chip_peaks(device)
-    if peaks is not None:
-        out["device_kind"] = peaks["device_kind"]
-        out["pct_of_hbm_peak"] = round(
-            100.0 * out["achieved_gbps"] / peaks["hbm_gbps"], 1)
-        if peaks.get("vpu_gops"):
-            out["pct_of_compute_peak"] = round(
-                100.0 * out["achieved_gflops"] / peaks["vpu_gops"], 1)
+    if peaks is None:
+        import jax
+
+        kind = getattr(device or jax.devices()[0], "device_kind", "")
+        raise ValueError(
+            f"no peak table entry for device_kind {kind!r} (known: "
+            f"{sorted(CHIP_PEAKS)}): a share of peak needs a known chip")
+    out["device_kind"] = peaks["device_kind"]
+    out["pct_of_hbm_peak"] = 100.0 * out["achieved_gbps"] / peaks["hbm_gbps"]
+    out["pct_of_vpu_estimate"] = (100.0 * out["achieved_gflops"]
+                                  / peaks["vpu_gops_estimate"])
     return out

@@ -622,10 +622,11 @@ def test_jaxpr_fused_flags_rule_distinguishes_xla_runner():
 
 # -- persistent compile cache -------------------------------------------------
 
-def test_configure_compile_cache(tmp_path):
+def test_configure_compile_cache(tmp_path, monkeypatch):
     from mpi_model_tpu.utils.compile_cache import (configure_compile_cache,
                                                    configured_dir)
 
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     assert configure_compile_cache(None) is None
     d = tmp_path / "cc"
     got = configure_compile_cache(str(d))
@@ -663,6 +664,7 @@ def test_compile_cache_populates_across_processes(tmp_path):
     )
     env = dict(__import__("os").environ)
     env.pop("JAX_ENABLE_X64", None)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     r1 = subprocess.run([_sys.executable, "-c", prog], env=env,
                         capture_output=True, text=True, timeout=300)
     assert r1.returncode == 0, r1.stderr[-2000:]
@@ -704,9 +706,10 @@ def test_cli_ensemble_impl_active_fused(capsys):
     assert rc == 0 and out["conserved"] and out["impl"] == "active_fused"
 
 
-def test_cli_compile_cache_flag(tmp_path, capsys):
+def test_cli_compile_cache_flag(tmp_path, capsys, monkeypatch):
     from mpi_model_tpu.cli import main
 
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     d = tmp_path / "cc"
     rc = main(["run", "--flow=diffusion", "--impl=active_fused",
                "--dimx=48", "--dimy=48", "--steps=1",

@@ -46,33 +46,33 @@ def test_bench_tolerance_lookup_clear_error():
         bench._tol_for(4, "float64")
 
 
+class _Chip:
+    platform = "tpu"
+    device_kind = "TPU v5 lite"
+
+
 def test_roofline_fields():
     """Roofline math: traffic amortizes over fused substeps, arithmetic
-    does not; unknown chips report measurements without invented peaks."""
+    does not; the VPU share is labeled an estimate."""
     from mpi_model_tpu.utils import stencil_roofline
 
-    r1 = stencil_roofline(1024, 4, t_step_s=1e-3, substeps=1)
-    r4 = stencil_roofline(1024, 4, t_step_s=1e-3, substeps=4)
+    r1 = stencil_roofline(1024, 4, t_step_s=1e-3, substeps=1, device=_Chip)
+    r4 = stencil_roofline(1024, 4, t_step_s=1e-3, substeps=4, device=_Chip)
     assert r1["bytes_per_step"] == 2 * 1024 * 1024 * 4
     assert r4["bytes_per_step"] == r1["bytes_per_step"] / 4
     assert r4["flops_per_step"] == r1["flops_per_step"]
     assert r1["achieved_gbps"] == r1["bytes_per_step"] / 1e-3 / 1e9
-    # CPU test rig: device_kind unknown → no percent-of-peak invented
-    assert r1["pct_of_hbm_peak"] is None or isinstance(
-        r1["pct_of_hbm_peak"], float)
+    assert r1["pct_of_hbm_peak"] == 100.0 * r1["achieved_gbps"] / 819.0
+    assert "pct_of_vpu_estimate" in r1 and r1["device_kind"] == "TPU v5 lite"
 
 
-def test_chip_peaks_prefix_matching_and_unknown_warning():
+def test_chip_peaks_prefix_matching_and_unknown_kinds():
     """device_kind strings drift across TPU generations: 'TPU v5p' and
     'TPU v5e' resolve via the ALIAS table to the right chips (letter
     suffixes are different parts — prefix matching would hand v5e the
-    v5p peaks), word-boundary prefixes match ('TPU v4 pod slice'), a
+    v5p peaks), word-boundary prefixes match ('TPU v4 pod slice'), and a
     letter suffix with no alias ('TPU v4i' — a genuinely different
-    inference chip) warns rather than inheriting wrong peaks, and
-    unknown TPU kinds warn instead of silently dropping the
-    percent-of-peak (round-4 ADVICE)."""
-    import warnings
-
+    inference chip) or an unknown kind has no entry."""
     from mpi_model_tpu.utils.roofline import CHIP_PEAKS, _lookup_peaks
 
     assert _lookup_peaks("TPU v5 lite") == CHIP_PEAKS["TPU v5 lite"]
@@ -80,27 +80,17 @@ def test_chip_peaks_prefix_matching_and_unknown_warning():
     assert _lookup_peaks("TPU v5e") == CHIP_PEAKS["TPU v5 lite"]
     assert _lookup_peaks("TPU v4 pod slice") == CHIP_PEAKS["TPU v4"]
     assert _lookup_peaks("TPU  v5   lite") == CHIP_PEAKS["TPU v5 lite"]
-    with pytest.warns(UserWarning, match="unrecognized TPU device_kind"):
-        assert _lookup_peaks("TPU v4i") == {}
-    with pytest.warns(UserWarning, match="unrecognized TPU device_kind"):
-        assert _lookup_peaks("TPU v99 hyper") == {}
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")  # second lookup: warn ONCE only
-        assert _lookup_peaks("TPU v99 hyper") == {}
-    # non-TPU kinds (CPU rigs) stay silent
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        assert _lookup_peaks("Host CPU") == {}
+    assert _lookup_peaks("TPU v4i") == {}
+    assert _lookup_peaks("TPU v99 hyper") == {}
+    assert _lookup_peaks("Host CPU") == {}
 
 
-def test_chip_peaks_env_override(monkeypatch):
-    from mpi_model_tpu.utils import chip_peaks
+def test_share_of_peak_on_an_unknown_device_is_an_error():
+    from mpi_model_tpu.utils import chip_peaks, stencil_roofline
 
-    monkeypatch.setenv("MMTPU_HBM_PEAK_GBPS", "500")
-    monkeypatch.setenv("MMTPU_VPU_PEAK_GOPS", "1000")
-    p = chip_peaks()
-    assert p is not None and p["hbm_gbps"] == 500.0
-    assert p["vpu_gops"] == 1000.0
+    assert chip_peaks() is None  # the CPU rig: no entry, no invented peak
+    with pytest.raises(ValueError, match="no peak table entry"):
+        stencil_roofline(1024, 4, t_step_s=1e-3)
 
 
 def test_ladder_config3_quick_has_gspmd_row():

@@ -92,8 +92,9 @@ def test_mesh_diffusion_bitwise_vs_single_device_and_serial(eight_devices):
             float(wrep.final_total["value"])
 
 
-def test_mesh_2d_batch_space_bitwise(eight_devices):
-    """The full 2-D layout: batch AND space both sharded."""
+def test_mesh_2d_batch_space_bitwise(eight_devices, assert_gspmd_close):
+    """The full 2-D layout: batch AND space both sharded. Space sharding
+    partitions the step, so values are held to the GSPMD bound."""
     spaces, models = make_scenarios(B=4)
     ref = run_ensemble(models[0], spaces, models=models,
                        executor=EnsembleExecutor(), steps=4)
@@ -102,9 +103,10 @@ def test_mesh_2d_batch_space_bitwise(eight_devices):
     got = run_ensemble(models[0], spaces, models=models,
                        executor=EnsembleExecutor(mesh=emesh), steps=4)
     for i in range(4):
-        assert bitwise(got[i][0].values["value"], ref[i][0].values["value"])
-        assert float(got[i][1].final_total["value"]) == \
-            float(ref[i][1].final_total["value"])
+        assert_gspmd_close(got[i][0].values["value"],
+                           ref[i][0].values["value"], 4)
+        assert_gspmd_close(got[i][1].final_total["value"],
+                           ref[i][1].final_total["value"], 4)
 
 
 def test_mesh_gray_scott_bitwise(eight_devices):
@@ -316,10 +318,12 @@ def test_cli_mesh_and_member_env_parsers():
         _parse_member_env(["bogus"])
 
 
-def test_service_mesh_stats_and_windowed_donation(eight_devices):
-    """The service facade with a mesh: results bitwise vs the meshless
-    service, stats surface the mesh, and the windowed donated dispatch
-    stays copy-free under the sharding constraints."""
+def test_service_mesh_stats_and_windowed_donation(eight_devices,
+                                                  assert_gspmd_close):
+    """The service facade with a mesh: results vs the meshless service
+    within the GSPMD bound (the mesh shards space), stats surface the
+    mesh, and the windowed donated dispatch stays copy-free under the
+    sharding constraints."""
     from mpi_model_tpu.ensemble import EnsembleService
 
     spaces, models = make_scenarios(B=4)
@@ -334,7 +338,7 @@ def test_service_mesh_stats_and_windowed_donation(eight_devices):
     svc.flush()
     for i, t in enumerate(ts):
         sp, _ = svc.result(t)
-        assert bitwise(sp.values["value"], want[i].values["value"])
+        assert_gspmd_close(sp.values["value"], want[i].values["value"], 4)
     st = svc.stats()
     assert st["mesh"] == {"batch": 2, "space": 2, "devices": 4}
     logged = [d for d in svc.scheduler.dispatch_log if "windows" in d]

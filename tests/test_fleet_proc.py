@@ -800,3 +800,27 @@ def test_tiering_wake_survives_proc_kill_fence():
     assert stats["respawns"] >= 1
     assert stats["shed"] == 0
     assert stats["wakes"] >= 2
+
+
+def test_spawn_refuses_a_member_that_asks_for_the_held_tpu(monkeypatch,
+                                                           tmp_path):
+    """A TPU belongs to one process: when this process holds it, a
+    process member whose environment asks for it is refused at spawn
+    (before any child starts), while a CPU-pinned member is not."""
+    from jax._src import xla_bridge
+
+    from mpi_model_tpu.ensemble import member_proc
+
+    monkeypatch.setattr(xla_bridge, "backends_are_initialized",
+                        lambda: True)
+    monkeypatch.setattr(xla_bridge, "backends",
+                        lambda: {"tpu": object(), "cpu": object()})
+    for env in ({"JAX_PLATFORMS": "tpu"}, {}):
+        with pytest.raises(RuntimeError, match="holds the TPU"):
+            member_proc.check_chip_free(env)
+    member_proc.check_chip_free({"JAX_PLATFORMS": "cpu"})
+    model = Model(Diffusion(0.1), 1.0, 1.0)
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    with pytest.raises(RuntimeError, match="holds the TPU"):
+        member_proc.spawn_process_member(
+            model, service_id="m0g0", member_kwargs={"steps": 1})

@@ -239,7 +239,7 @@ def test_ir_model_bitwise_across_serial_impls(name):
 
 
 @pytest.mark.parametrize("name", ALL_MODELS)
-def test_ir_model_bitwise_sharded(name, eight_devices):
+def test_ir_model_bitwise_sharded(name, eight_devices, assert_gspmd_close):
     model, space = build_model(name, 32, dtype=jnp.float64)
     want, _ = model.execute(space, steps=8)
     for ex in (ShardMapExecutor(make_mesh(4, devices=eight_devices[:4])),
@@ -248,7 +248,11 @@ def test_ir_model_bitwise_sharded(name, eight_devices):
                AutoShardedExecutor(make_mesh(4,
                                              devices=eight_devices[:4]))):
         out, rep = model.execute(space, ex, steps=8)
-        assert bitwise_equal(out, want), (name, type(ex).__name__)
+        if isinstance(ex, AutoShardedExecutor):
+            for k in want.values:
+                assert_gspmd_close(out.values[k], want.values[k], 8)
+        else:
+            assert bitwise_equal(out, want), (name, type(ex).__name__)
         assert rep.comm_size == 4
 
 

@@ -75,7 +75,8 @@ def test_audit_detects_an_unmarked_heavy_test(tmp_path):
 def test_audit_detects_an_unmarked_big_grid_test(tmp_path):
     """The >= 2048² grid check (ISSUE 3 satellite) must catch literal,
     tuple, keyword and name-propagated grid constructions — and must
-    NOT flag a single big literal (a strip, a byte count)."""
+    NOT flag a single big literal (a strip, a byte count) or a shape
+    description (``jax.ShapeDtypeStruct`` and helpers returning one)."""
     p = tmp_path / "test_fake_grid.py"
     p.write_text(
         "g = 4096\n\n"
@@ -90,7 +91,12 @@ def test_audit_detects_an_unmarked_big_grid_test(tmp_path):
         "def test_strip_ok():\n"
         "    ones((1024, 2048))\n\n"
         "def test_bytes_ok():\n"
-        "    limit(65536)\n")
+        "    limit(65536)\n\n"
+        "def _sds(shape):\n"
+        "    return jax.ShapeDtypeStruct(shape, 'float32')\n\n"
+        "def test_shape_only_ok():\n"
+        "    jax.ShapeDtypeStruct((16384, 16384), 'float32')\n"
+        "    _sds((16384, 16384))\n")
     vio = _audit_module(p)
     assert vio == ["test_fake_grid.py::test_literal",
                    "test_fake_grid.py::test_via_name",

@@ -305,7 +305,7 @@ def test_global_sum_psum(mesh1d):
     def f(xl):
         return global_sum(xl, "x")
 
-    from mpi_model_tpu.compat import shard_map
+    from jax import shard_map
     got = jax.jit(shard_map(f, mesh=mesh1d, in_specs=P("x", None),
                             out_specs=P()))(x)
     assert float(got) == pytest.approx(float(x.sum()))

@@ -451,17 +451,22 @@ def test_run_soak_rejects_bad_rate():
 def test_scheduler_arms_persistent_compile_cache_by_default(tmp_path,
                                                             monkeypatch):
     from mpi_model_tpu.ensemble import EnsembleScheduler
-    from mpi_model_tpu.utils.compile_cache import default_cache_dir
+    from mpi_model_tpu.utils.compile_cache import CHECKOUT_CACHE, cache_dir
 
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
-                       str(tmp_path / "cc"))
-    assert default_cache_dir() == str(tmp_path / "cc")
-    sch = EnsembleScheduler()
-    assert sch.compile_cache == str(tmp_path / "cc")
-    # explicit None disables; explicit dir pins
-    assert EnsembleScheduler(compile_cache=None).compile_cache is None
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert cache_dir() == CHECKOUT_CACHE
+    assert EnsembleScheduler().compile_cache == CHECKOUT_CACHE
     pinned = EnsembleScheduler(compile_cache=str(tmp_path / "p"))
     assert pinned.compile_cache == str(tmp_path / "p")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                       str(tmp_path / "cc"))
+    assert cache_dir() == str(tmp_path / "cc")
+    sch = EnsembleScheduler()
+    assert sch.compile_cache == str(tmp_path / "cc")
+    # explicit None disables; an explicit dir gives way to the env var
+    assert EnsembleScheduler(compile_cache=None).compile_cache is None
+    pinned = EnsembleScheduler(compile_cache=str(tmp_path / "p"))
+    assert pinned.compile_cache == str(tmp_path / "cc")
     # the service surfaces the armed dir
     svc = EnsembleService(scen_model(), steps=1,
                           compile_cache=str(tmp_path / "cc"))
