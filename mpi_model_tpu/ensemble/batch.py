@@ -51,6 +51,7 @@ from ..core.cellular_space import CellularSpace, first_float_dtype
 from ..models.model import (ConservationError, Model, Report,
                             default_conservation_rtol)
 from ..resilience import inject, lockdep
+from ..utils.tracing import get_tracer
 from ..ops.flow import Diffusion, PointFlow, build_outflow
 from ..ops.stencil import neighbor_counts_traced, point_flow_step, transport
 
@@ -988,7 +989,12 @@ def complete_ensemble(inflight: EnsembleInFlight, *,
     # analysis: ignore[naked-timer] — the fetch-segment anchor of
     # the same billing split (see the wall comment below)
     fetch_t0 = _time.perf_counter()
-    out = jax.tree.map(jax.block_until_ready, inflight.out)
+    # each host wait and read has a span of its own (block, totals,
+    # lanes), so the innermost open span names what the host did while
+    # the device sat idle
+    tracer = get_tracer()
+    with tracer.span("ensemble.fetch.block"):
+        out = jax.tree.map(jax.block_until_ready, inflight.out)
     # the batch wall bills the HOST-OBSERVED dispatch segments: launch
     # (assembly + device enqueue) plus fetch (block + transfer). Under
     # the async loop, the gap between them is the overlap window —
@@ -1006,18 +1012,11 @@ def complete_ensemble(inflight: EnsembleInFlight, *,
     # [B] active-tile) stat lanes alongside the values; fold them into
     # backend_report so a batch that dense-fell-back every step is
     # visible, not silently labeled "active" (serial/sharded contract)
-    fb_arr = at_arr = ff_arr = None
-    if executor.impl == "active":
-        out, (fb_b, at_b) = out
-        fb_arr = np.asarray(fb_b)
-        at_arr = np.asarray(at_b)
-    elif executor.impl == "active_fused":
-        # the fused lanes also carry the [B] flags_fused counter —
-        # passes whose next-step flags came out of the kernel
-        out, (fb_b, at_b, ff_b) = out
-        fb_arr = np.asarray(fb_b)
-        at_arr = np.asarray(at_b)
-        ff_arr = np.asarray(ff_b)
+    # (the fused lanes also carry the [B] flags_fused counter — passes
+    # whose next-step flags came out of the kernel)
+    stat_lanes = ()
+    if executor.impl in ("active", "active_fused"):
+        out, stat_lanes = out
     # launch-captured lane poisons (lane_nan) + the fetch-boundary seam
     poisons = list(inflight.poisons)
     st = inject.active()
@@ -1028,8 +1027,18 @@ def complete_ensemble(inflight: EnsembleInFlight, *,
     for lane, fault in poisons:
         out = inject.poison_lane_values(out, lane, fault)
     mesh = getattr(executor, "mesh", None)
-    final_d = batched_totals(out if mesh is None
-                             else mesh.totals_view(out))
+    with tracer.span("ensemble.fetch.totals", lanes=count):
+        fb_arr, at_arr, ff_arr = ([np.asarray(x) for x in stat_lanes]
+                                  + [None] * 3)[:3]
+        final_d = batched_totals(out if mesh is None
+                                 else mesh.totals_view(out))
+        last_exec = np.asarray(
+            executor.last_execute_for(model, espace)(out, rates_b,
+                                                     frozens_b),
+            np.float64)
+        initial = {k: np.asarray(v, np.float64)
+                   for k, v in inflight.initial_d.items()}
+        final = {k: np.asarray(v, np.float64) for k, v in final_d.items()}
     executor.last_impl = executor.impl
     executor.last_backend_report = None
     if fb_arr is not None:
@@ -1068,13 +1077,6 @@ def complete_ensemble(inflight: EnsembleInFlight, *,
                 "per_lane_flags_fused": [int(x) for x in ff_arr[:count]],
             })
 
-    last_exec = np.asarray(
-        executor.last_execute_for(model, espace)(out, rates_b, frozens_b),
-        np.float64)
-
-    initial = {k: np.asarray(v, np.float64)
-               for k, v in inflight.initial_d.items()}
-    final = {k: np.asarray(v, np.float64) for k, v in final_d.items()}
     # IR models check the VIEW (summed mass minus integrated budgets,
     # plus per-term contract keys), not raw per-channel totals — a
     # declared source's drift is physics, an undeclared one a violation
@@ -1105,34 +1107,35 @@ def complete_ensemble(inflight: EnsembleInFlight, *,
     out_es = dataclasses.replace(espace, values=dict(out))
     results: list = []
     badset = set(bad)
-    for i in range(count):
-        if i in badset:
-            e = _violation_error(errs, thresholds, i,
-                                 key=wkeys[i] if wkeys else None,
-                                 model=model)
-            # the batch's wall time rides the error too, so serving
-            # counters stay honest even when every lane violated
-            e.wall_time_s = wall
-            results.append(e)
-            continue
-        sp = out_es.scenario(i)
-        results.append((sp, Report(
-            comm_size=1,
-            rank_id=jax.process_index(),
-            steps=num_steps,
-            initial_total={k: float(initial[k][i]) for k in initial},
-            final_total={k: float(final[k][i]) for k in final},
-            last_execute=[float(x) for x in last_exec[i]],
-            wall_time_s=wall,
-            backend_report=(None if fb_arr is None else {
-                "impl": executor.impl,
-                "fallback_steps": int(fb_arr[i]),
-                "mean_active_fraction": (
-                    float(at_arr[i]) / denom if denom else None),
-                **({} if ff_arr is None
-                   else {"flags_fused": int(ff_arr[i])}),
-            }),
-        )))
+    with tracer.span("ensemble.fetch.lanes", lanes=count):
+        for i in range(count):
+            if i in badset:
+                e = _violation_error(errs, thresholds, i,
+                                     key=wkeys[i] if wkeys else None,
+                                     model=model)
+                # the batch's wall time rides the error too, so serving
+                # counters stay honest even when every lane violated
+                e.wall_time_s = wall
+                results.append(e)
+                continue
+            sp = out_es.scenario(i)
+            results.append((sp, Report(
+                comm_size=1,
+                rank_id=jax.process_index(),
+                steps=num_steps,
+                initial_total={k: float(initial[k][i]) for k in initial},
+                final_total={k: float(final[k][i]) for k in final},
+                last_execute=[float(x) for x in last_exec[i]],
+                wall_time_s=wall,
+                backend_report=(None if fb_arr is None else {
+                    "impl": executor.impl,
+                    "fallback_steps": int(fb_arr[i]),
+                    "mean_active_fraction": (
+                        float(at_arr[i]) / denom if denom else None),
+                    **({} if ff_arr is None
+                       else {"flags_fused": int(ff_arr[i])}),
+                }),
+            )))
     return results
 
 

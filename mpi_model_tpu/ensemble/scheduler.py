@@ -300,22 +300,24 @@ class EnsembleScheduler:
         """Queue one scenario; returns its ticket. The group dispatches
         immediately once it holds ``max_batch`` scenarios (unless
         ``inline_dispatch=False`` — then the pump thread owns it)."""
-        steps = model.num_steps if steps is None else int(steps)
-        key = structure_key(model, space) + (steps,)
-        # the submitter's current trace context rides the ticket: a
-        # caller that opened a span (the fleet's submit span — locally
-        # or re-attached from the wire's trace meta) becomes the parent
-        # of every dispatch span this scenario generates
-        trace = get_tracer().current()
-        with self._lock:
-            ticket = next(self._ids)
-            self._queues.setdefault(key, []).append(
-                _Pending(ticket, space, model, steps, self._clock(),
-                         trace))
-            self._pending_tickets.add(ticket)
-            full = len(self._queues[key]) >= self.max_batch
-        get_recorder().record("submit", service_id=self.service_id,
-                              ticket=ticket, steps=steps)
+        with get_tracer().span("ensemble.submit") as sm:
+            steps = model.num_steps if steps is None else int(steps)
+            key = structure_key(model, space) + (steps,)
+            # this span's context rides the ticket: it parents every
+            # dispatch span the scenario generates, and sits under the
+            # caller's span where there is one (the fleet's submit span,
+            # locally or re-attached from the wire's trace meta)
+            trace = get_tracer().current()
+            with self._lock:
+                ticket = next(self._ids)
+                self._queues.setdefault(key, []).append(
+                    _Pending(ticket, space, model, steps, self._clock(),
+                             trace))
+                self._pending_tickets.add(ticket)
+                full = len(self._queues[key]) >= self.max_batch
+            sm["ticket"] = ticket
+            get_recorder().record("submit", service_id=self.service_id,
+                                  ticket=ticket, steps=steps)
         if full and self.inline_dispatch:
             self._dispatch_group(key)
         return ticket
@@ -855,19 +857,23 @@ class EnsembleScheduler:
                                      wall)
             return
         failed: list[int] = []
-        for it, res in zip(items, results):
-            if isinstance(res, Exception) and self.retry == "solo":
-                if k > 1:
-                    # a failed scenario in a batch: re-dispatch it solo
-                    # once — its batchmates' results are never touched
-                    failed.append(it.ticket)
-                else:
-                    # it already ran alone: nothing left to distinguish
-                    self._quarantine(it, res, attempts=1)
-                continue
-            if isinstance(res, Exception):
-                res.ticket = it.ticket
-            self._publish(it, res)
+        with get_tracer().span("ensemble.publish", parent=items[0].trace,
+                               **self._span_meta(items, bucket)):
+            for it, res in zip(items, results):
+                if isinstance(res, Exception) and self.retry == "solo":
+                    if k > 1:
+                        # a failed scenario in a batch: re-dispatch it
+                        # solo once — its batchmates' results are never
+                        # touched
+                        failed.append(it.ticket)
+                    else:
+                        # it already ran alone: nothing left to
+                        # distinguish
+                        self._quarantine(it, res, attempts=1)
+                    continue
+                if isinstance(res, Exception):
+                    res.ticket = it.ticket
+                self._publish(it, res)
         # the retry budget splits the failed lanes BEFORE the log entry
         # is written, so the entry reconciles with what actually runs
         # (the solo counter only moves on this dispatch path, so the

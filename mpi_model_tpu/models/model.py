@@ -998,19 +998,27 @@ class Model:
 
         from ..utils.tracing import trace_span
 
+        # every host read of the device has a span of its own, so the
+        # innermost open span names what the host did while it idled
         with trace_span("model.execute", steps=num_steps,
                         executor=type(executor).__name__):
-            initial = {k: float(space.total(k)) for k in space.values}
+            with trace_span("model.totals.initial",
+                            channels=len(space.values)):
+                initial = {k: float(space.total(k)) for k in space.values}
             t0 = _time.perf_counter()
-            with trace_span("executor.run"):
+            with trace_span("executor.enqueue"):
                 out_values = executor.run_model(self, space, num_steps)
+            with trace_span("executor.block"):
                 out_values = jax.tree.map(jax.block_until_ready, out_values)
             wall = _time.perf_counter() - t0
 
-            with trace_span("model.report"):
-                out_space = space.with_values(out_values)
+            out_space = space.with_values(out_values)
+            with trace_span("model.totals.final",
+                            channels=len(out_space.values)):
                 final = {k: float(out_space.total(k))
                          for k in out_space.values}
+            with trace_span("model.flows.last_execute",
+                            flows=len(self.flows)):
                 last_exec = [float(f.execute(out_space))
                              for f in self.flows]
 

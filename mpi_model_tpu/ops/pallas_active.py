@@ -329,6 +329,7 @@ def _fused_compute_call(padded, ids, cnt1, selfnz, origin, *, rate, plan,
     rate1 = jnp.reshape(jnp.asarray(rate, dtype=rate_dt), (1,))
     return pl.pallas_call(
         kernel,
+        name="mm_active_compute",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((K, th, tw), jnp.dtype(dtype)),
@@ -400,6 +401,7 @@ def _fused_scatter_call(padded, upd, ids, cnt1, *, plan, ring, interpret):
     )
     return pl.pallas_call(
         kernel,
+        name="mm_active_scatter",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(padded.shape, padded.dtype),
         # operand order: (ids, cnt1, upd, padded) — index 3 is the state

@@ -524,6 +524,7 @@ def _stencil_call(v, halo_operands, *, rate, block, offsets, interpret,
                                     memory_space=pltpu.SMEM)])
     return pl.pallas_call(
         kernel,
+        name="mm_stencil" if halo_operands is None else "mm_stencil_halo",
         grid=(gi, gj),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bh, bw), lambda i, j: (i, j)),
@@ -726,6 +727,7 @@ def _pipeline_call(v, *, rate, block, offsets, interpret, nsteps,
     ]
     return pl.pallas_call(
         kernel,
+        name="mm_stencil_pipeline",
         grid=(gi, gj),
         in_specs=specs,
         out_specs=pl.BlockSpec((bh, bw), lambda i, j: (i, j)),
@@ -1378,6 +1380,7 @@ def _field_call(chans, names, flows, *, block, offsets, interpret, nsteps,
                                      memory_space=pltpu.SMEM)])
     return pl.pallas_call(
         kernel,
+        name="mm_field_halo" if halo else "mm_field",
         grid=(gi, gj),
         in_specs=in_specs,
         out_specs=[pl.BlockSpec((bh, bw), lambda i, j: (i, j))] * n_out,

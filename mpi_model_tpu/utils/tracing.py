@@ -32,19 +32,32 @@ speed"). Here tracing is structured and first-class:
   a member exports its completed-span deltas as plain dicts
   (wall-clock-anchored, so merged timelines order across processes)
   and the supervisor absorbs them into its own ring;
-- ``device_trace()`` — wraps ``jax.profiler.trace`` so host spans and
-  the XLA/TPU device profile are captured over the same window (this is
-  how BASELINE's halo-exchange share is attributed on real hardware);
+- the profiler's trace: while a tracer is enabled, every span also
+  opens a ``jax.profiler.TraceAnnotation`` named ``mm.<span name>``
+  (the name only; meta stays in the ring), so any profiler capture
+  (``jax.profiler.trace``, TensorBoard) holds the program's spans on
+  the profiler's clock, beside the device's ops;
 - a process-wide default tracer (``get_tracer``/``trace_span``) that the
-  framework's own phases report into: ``Model.execute`` emits
-  ``model.execute`` / ``executor.run``, the sharded executors emit their
-  build-vs-run phases, the serving stack emits per-dispatch
-  assemble/launch/fetch and per-wake spans.
+  framework's own phases report into. ``Model.execute`` emits
+  ``model.execute`` around ``model.totals.initial`` (the per-channel
+  totals read before the run), ``executor.enqueue`` (the
+  ``run_model`` call), ``executor.block`` (waiting for the device),
+  ``model.totals.final`` and ``model.flows.last_execute``; the sharded
+  executors emit their build-vs-run phases; the serving stack emits
+  ``ensemble.submit`` per scenario and, per dispatch,
+  ``ensemble.assemble``/``launch``/``fetch`` with ``fetch`` split into
+  ``ensemble.fetch.block``, ``ensemble.fetch.totals`` and
+  ``ensemble.fetch.lanes``, then ``ensemble.publish`` (the results'
+  fan-out to their tickets), plus per-wake spans. Each host read of the
+  device has a span of its own, so the innermost open span names what
+  the host did while the device sat idle.
 
-Recording one span is two ``perf_counter`` calls, two id formats and a
-list append — cheap enough to leave on (the bench's
-``tracing_overhead_frac`` field gates the claim with a measured
-number); ``Tracer(enabled=False)`` makes it free.
+Recording one span costs two ``perf_counter`` calls, two id formats, a
+list append and one annotation enter/exit (the annotation, about 1 µs,
+records nothing unless a profiler captures). ``PERF.md`` gives what
+that costs a long run and a served sweep on a TPU v5e, tracer on
+against off and capture on against off; ``Tracer(enabled=False)``
+makes it free.
 """
 
 from __future__ import annotations
@@ -59,6 +72,8 @@ import threading
 import time
 from typing import Any, Iterator, Optional
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
     "Span",
     "TraceContext",
@@ -72,6 +87,9 @@ __all__ = [
 #: processes (a fleet and its spawned members) can never collide —
 #: no randomness needed, and ids stay stable/debuggable
 _ids = itertools.count(1)
+
+#: the profiler annotation of span ``name`` is ``mm.<name>``
+ANNOTATION_PREFIX = "mm."
 
 
 def _new_id() -> str:
@@ -237,21 +255,24 @@ class Tracer:
         span_id = _new_id()
         stack = self._ctx_stack()
         stack.append(TraceContext(trace_id, span_id))
-        t0 = time.perf_counter()
-        try:
-            yield meta
-        finally:
-            dt = time.perf_counter() - t0
-            stack.pop()
-            # analysis: ignore[unguarded-shared-mutation] — threading.local
-            # storage: each thread mutates only its own depth slot
-            self._local.depth = depth
-            s = Span(name=name, start_s=t0, duration_s=dt,
-                     thread=threading.get_ident(), depth=depth,
-                     meta=dict(meta), trace_id=trace_id, span_id=span_id,
-                     parent_id=(p.span_id if p is not None else None),
-                     start_wall_s=t0 + self._wall_off, pid=self._pid)
-            self._append(s)
+        # the same block on the profiler's clock, enclosing the span
+        with TraceAnnotation(ANNOTATION_PREFIX + name):
+            t0 = time.perf_counter()
+            try:
+                yield meta
+            finally:
+                dt = time.perf_counter() - t0
+                stack.pop()
+                # analysis: ignore[unguarded-shared-mutation] — per-thread
+                # (threading.local) depth slot, mutated by its thread only
+                self._local.depth = depth
+                s = Span(name=name, start_s=t0, duration_s=dt,
+                         thread=threading.get_ident(), depth=depth,
+                         meta=dict(meta), trace_id=trace_id,
+                         span_id=span_id,
+                         parent_id=(p.span_id if p is not None else None),
+                         start_wall_s=t0 + self._wall_off, pid=self._pid)
+                self._append(s)
 
     def instant(self, name: str, **meta: Any) -> None:
         """Record a zero-duration marker (the structured version of the
@@ -505,22 +526,6 @@ class Tracer:
                        "displayTimeUnit": "ms",
                        "dropped": self.dropped}, f)
         return path
-
-    # -- device profiling ----------------------------------------------------
-
-    @contextlib.contextmanager
-    def device_trace(self, logdir: str, name: str = "device_trace"
-                     ) -> Iterator[None]:
-        """Capture an XLA device profile (``jax.profiler.trace``) over the
-        block, alongside a host span of the same name — so host phases
-        can be lined up against compiled-program device time (the way
-        BASELINE's halo-exchange wallclock share is attributed on real
-        hardware)."""
-        import jax
-
-        with self.span(name, logdir=logdir):
-            with jax.profiler.trace(logdir):
-                yield
 
 
 # -- process-wide default tracer ---------------------------------------------

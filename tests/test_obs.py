@@ -214,6 +214,13 @@ def test_run_soak_dumps_snapshots_on_an_interval(fresh_obs, tmp_path):
 
 # -- trace-context propagation ------------------------------------------------
 
+def _submit_parent(tr, span):
+    """The ``ensemble.submit`` span that parents a dispatch span: the
+    scheduler's span of the ticket, itself under the caller's span."""
+    return next(s for s in tr.spans if s.name == "ensemble.submit"
+                and s.span_id == span.parent_id)
+
+
 def test_dispatch_spans_parent_under_fleet_submit_span(fresh_obs):
     tr, _ = fresh_obs
     fleet = FleetSupervisor(scen_model(), services=2, steps=4,
@@ -227,7 +234,9 @@ def test_dispatch_spans_parent_under_fleet_submit_span(fresh_obs):
                  "ensemble.fetch"):
         sp = next(s for s in tr.spans if s.name == name)
         assert sp.trace_id == sub.trace_id
-        assert sp.parent_id == sub.span_id
+        esub = _submit_parent(tr, sp)
+        assert esub.parent_id == sub.span_id
+        assert esub.meta["ticket"] in sp.meta["tickets"]
         assert t in sp.meta["tickets"]
 
 
@@ -247,7 +256,7 @@ def test_dispatch_spans_parent_across_the_loopback_wire(fresh_obs):
     sub = next(s for s in tr.spans if s.name == "fleet.submit")
     launch = next(s for s in tr.spans if s.name == "ensemble.launch")
     assert launch.trace_id == sub.trace_id
-    assert launch.parent_id == sub.span_id
+    assert _submit_parent(tr, launch).parent_id == sub.span_id
 
 
 def test_wake_spans_join_the_tickets_trace(fresh_obs, tmp_path):
@@ -289,9 +298,10 @@ def test_timeline_of_a_two_ticket_run_is_gap_free(fresh_obs, tmp_path):
         tl = obs.timeline(t, journal_dir=jd, spans=tr.spans)
         assert tl.complete and not tl.gaps
         kinds = [e.kind for e in tl.events]
-        # the submit SPAN opens before the journal's submit record is
-        # appended — both lead the timeline, in that order
-        assert kinds[0] == "fleet.submit" and kinds[1] == "submit"
+        # the fleet's submit SPAN opens, the member scheduler's submit
+        # span inside it, then the journal's submit record is appended —
+        # the three lead the timeline, in that order
+        assert kinds[:3] == ["fleet.submit", "ensemble.submit", "submit"]
         assert "served" in kinds
         assert "ensemble.launch" in kinds  # spans joined by trace id
         # ordered: every stamped event's t_wall is non-decreasing
@@ -430,7 +440,8 @@ def test_loopback_hard_stop_timelines_complete_and_trace_merged(
     sub_ids = {s.span_id for s in tr.spans if s.name == "fleet.submit"}
     launches = [s for s in tr.spans if s.name == "ensemble.launch"]
     assert launches
-    assert all(s.parent_id in sub_ids for s in launches)
+    assert all(_submit_parent(tr, s).parent_id in sub_ids
+               for s in launches)
 
     # (3) the flight recorder dumped beside the fence's FailureEvent
     assert any(d["reason"] == "fence" and d["service_id"] == victim

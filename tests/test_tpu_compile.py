@@ -41,9 +41,12 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=sharding)
 
 
-def _compile(fn, *args):
+def _compile(fn, *args, kernels=()):
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text  # the Pallas kernel is in the program
+    # each kernel's op carries its stable name in the device's trace
+    for name in kernels:
+        assert f"%{name}." in text, name
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -51,7 +54,7 @@ def test_dense_kernel(one_chip, dtype):
     from mpi_model_tpu.ops.pallas_stencil import pallas_dense_step
 
     _compile(lambda v: pallas_dense_step(v, 0.1, interpret=False),
-             _sds((16384, 16384), dtype, one_chip))
+             _sds((16384, 16384), dtype, one_chip), kernels=["mm_stencil"])
 
 
 def test_field_kernel(one_chip):
@@ -62,7 +65,8 @@ def test_field_kernel(one_chip):
         grid.shape, [Diffusion(0.1, attr="a"),
                      Coupled(flow_rate=0.05, attr="a", modulator="b"),
                      Diffusion(0.2, attr="b")], interpret=False)
-    _compile(lambda a, b: step({"a": a, "b": b}), grid, grid)
+    _compile(lambda a, b: step({"a": a, "b": b}), grid, grid,
+             kernels=["mm_field"])
 
 
 def test_composed_vpu_kernel(one_chip):
@@ -88,7 +92,7 @@ def test_halo_shard_kernel(one_chip):
     _compile(lambda v, r, o: pallas_halo_step(v, r, o, (2 * h, 2 * w), 0.1,
                                               interpret=False, nsteps=d),
              _sds((h, w), f32, one_chip), ring,
-             _sds((2,), "int32", one_chip))
+             _sds((2,), "int32", one_chip), kernels=["mm_stencil_halo"])
 
 
 @pytest.mark.parametrize("dtype, k, ring", [
@@ -108,4 +112,5 @@ def test_fused_active_kernel(one_chip, dtype, k, ring):
                  jnp.dtype(dtype), k=k, ring=ring, interpret=False),
              _sds((16384 + 2 * ring, 16384 + 2 * ring), dtype, one_chip),
              lanes, _sds((), "int32", one_chip), lanes,
-             _sds((2,), "int32", one_chip))
+             _sds((2,), "int32", one_chip),
+             kernels=["mm_active_compute", "mm_active_scatter"])
