@@ -235,6 +235,8 @@ class FlowIRModel(Model):
         step.impl = "active" if impl == "active" else (
             "composed" if impl == "composed" else "xla")
         step.substeps = substeps
+        # the nonlinear lowering is XLA fusions, "composed" included
+        step.kernel_pass = False
         step.composed_k = 1 if impl == "composed" else None
         step.composed_passes = substeps if impl == "composed" else None
         self._step_cache[key] = step

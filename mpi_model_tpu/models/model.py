@@ -153,6 +153,11 @@ class SerialExecutor:
         #: cannot vouch for it (dense/composed/point paths, a poisoned
         #: chunk), which makes the consumer fall back to the diff.
         self.last_dirty_tiles: Optional[dict] = None
+        #: ``{"passes", "paired_passes"}`` of the last run whose step is
+        #: a Pallas kernel: its kernel passes, and how many ran two to a
+        #: loop iteration (``ops.pallas_stencil.paired_loop``); None
+        #: after a run of any other step
+        self.last_kernel_passes: Optional[dict] = None
         self._cache: dict = {}
 
     def run_model(self, model: "Model", space: CellularSpace,
@@ -188,6 +193,7 @@ class SerialExecutor:
         # likewise the dirty-tile export: a stale map from a previous
         # active run must never describe THIS run's output
         self.last_dirty_tiles = None
+        self.last_kernel_passes = None
         # all-point-flow models step only the ≤9k involved cells in the
         # compiled loop (one O(grid) gather/scatter per RUN, bitwise
         # equal to the full-grid path) — the reference's live workload
@@ -435,18 +441,35 @@ class SerialExecutor:
         key = (stepk, step1)
         runner = self._cache.get(key)
         if runner is None:
-            def _run(v, nq, nr):
-                def loop(fn, c, count):
-                    return jax.lax.fori_loop(
-                        0, count, lambda i, carry: fn(carry), c)
-                if stepk is not None:
-                    v = loop(stepk, v, nq)
-                if step1 is not None:
-                    v = loop(step1, v, nr)
-                return v
-            runner = jax.jit(_run)
+            runner = _loop_runner(stepk, step1)
             self._cache[key] = runner
+        if step_any is not None and step_any.kernel_pass:
+            self.last_kernel_passes = {
+                "passes": q + r, "paired_passes": q - q % 2 + r - r % 2}
         return runner(dict(space.values), jnp.int32(q), jnp.int32(r))
+
+
+def _loop_runner(stepk, step1):
+    """The jitted time loop of ``SerialExecutor``'s generic path:
+    ``nq`` calls of ``stepk``, then ``nr`` of ``step1`` (trip counts
+    traced). A step of whole-state Pallas kernel passes
+    (``step.kernel_pass``) runs two calls per loop iteration, which
+    spares XLA a copy of the whole state before each call; every other
+    step keeps one call per iteration."""
+    from ..ops.pallas_stencil import paired_loop
+
+    def loop(fn, c, count):
+        if fn.kernel_pass:
+            return paired_loop(fn, count, c)
+        return jax.lax.fori_loop(0, count, lambda i, carry: fn(carry), c)
+
+    def _run(v, nq, nr):
+        if stepk is not None:
+            v = loop(stepk, v, nq)
+        if step1 is not None:
+            v = loop(step1, v, nr)
+        return v
+    return jax.jit(_run)
 
 
 class Model:
@@ -932,6 +955,11 @@ class Model:
                                        or pallas_field_stepper is not None)
                      else "xla")
         step.substeps = substeps
+        # whether each call runs whole-state Pallas kernel passes, which
+        # the executors' loops run two to an iteration (paired_loop)
+        step.kernel_pass = (pallas_steppers is not None
+                            or pallas_field_stepper is not None
+                            or composed_steppers is not None)
         # auto-k visibility (ISSUE 3 satellite): the chosen composed k
         # rides the step so executors/Reports can record it — the fused
         # active impl composes the same way (k·passes == substeps, the
@@ -1006,8 +1034,10 @@ class Model:
                             channels=len(space.values)):
                 initial = {k: float(space.total(k)) for k in space.values}
             t0 = _time.perf_counter()
-            with trace_span("executor.enqueue"):
+            with trace_span("executor.enqueue") as meta:
                 out_values = executor.run_model(self, space, num_steps)
+                meta.update(getattr(executor, "last_kernel_passes", None)
+                            or {})
             with trace_span("executor.block"):
                 out_values = jax.tree.map(jax.block_until_ready, out_values)
             wall = _time.perf_counter() - t0

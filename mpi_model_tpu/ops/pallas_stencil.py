@@ -897,6 +897,26 @@ def resolve_interpret(values=None) -> bool:
     return jax.default_backend() == "cpu"
 
 
+def paired_loop(body, count, init, odd=None):
+    """``body`` applied ``count`` times (``count`` traced), two passes
+    per loop iteration, then one more pass (``odd``, else ``body``)
+    when ``count`` is odd.
+
+    A Pallas kernel cannot write into the buffer it reads, and a while
+    loop keeps its carry in one buffer: with one kernel pass per
+    iteration XLA copies the whole carry aside before every pass. With
+    two, the second pass writes into the carry buffer that the first
+    pass's read freed, and no copy is made. The result is bitwise that
+    of ``count`` single passes.
+
+    The odd pass runs in a loop of its own, of at most one trip, whose
+    body keeps the copy: XLA:CPU compiles an interpreted kernel
+    differently outside a loop body (a ``cond`` there moved the last bit
+    of some cells), and a loop keeps it bitwise."""
+    init = lax.fori_loop(0, count // 2, lambda i, c: body(body(c)), init)
+    return lax.fori_loop(0, count % 2, lambda i, c: (odd or body)(c), init)
+
+
 def _validate_block(h: int, w: int,
                     block: tuple[int, int]) -> tuple[int, int]:
     """Clamp an oversized block to the grid, then require exact tiling —

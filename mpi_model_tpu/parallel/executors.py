@@ -210,6 +210,10 @@ class ShardMapExecutor:
         #: per-run report detail (Report.backend_report); None until a
         #: run records one
         self.last_backend_report: Optional[dict] = None
+        #: ``{"passes", "paired_passes"}`` of the last Pallas halo run:
+        #: its kernel passes, and how many ran two to a loop iteration
+        #: (``ops.pallas_stencil.paired_loop``); None after any other run
+        self.last_kernel_passes: Optional[dict] = None
         self._cache: dict = {}
 
     @property
@@ -316,6 +320,7 @@ class ShardMapExecutor:
         #: per-run report detail (Report.backend_report) — reset so a
         #: previous run's composed record never leaks forward
         self.last_backend_report = None
+        self.last_kernel_passes = None
         # origin is part of the identity: the compiled runners bake
         # row0/col0 and the boundary mask from it, so two same-shaped
         # partitions at different origins must not share a runner. The
@@ -495,14 +500,21 @@ class ShardMapExecutor:
         composed path's k IS ``halo_depth`` and the remainder chunk
         (``num_steps % k``) composes at its own depth — both recorded
         in ``Report.backend_report`` so a depth that buys no
-        composition is observable."""
-        if kind != "composed":
+        composition is observable. A Pallas run also records its
+        kernel passes (``last_kernel_passes``)."""
+        if kind == "xla":
             return
         d = self.halo_depth
+        q = num_steps // d
+        self.last_kernel_passes = {
+            "passes": q + int(num_steps % d > 0),
+            "paired_passes": q - q % 2}
+        if kind != "composed":
+            return
         self.last_backend_report = {
             "impl": "composed",
             "composed_k": d,
-            "full_chunks": num_steps // d,
+            "full_chunks": q,
             "remainder_chunk_depth": num_steps % d,
         }
 
@@ -776,7 +788,8 @@ class ShardMapExecutor:
         from jax import lax
 
         from ..ops.pallas_stencil import (
-            mesh_interpret, pallas_field_halo_step, pallas_halo_step,
+            mesh_interpret, paired_loop, pallas_field_halo_step,
+            pallas_halo_step,
         )
         from .halo import exchange_ring, zero_ring
 
@@ -810,54 +823,72 @@ class ShardMapExecutor:
             origin = jnp.stack([row0, col0]).astype(jnp.int32)
 
             cdt = self.compute_dtype
-            if kind == "diffusion":
-                def chunk(c, ns):
-                    """ns fused steps after one depth-``ns`` exchange
-                    (the remainder chunk ships only the rings it
-                    consumes); flow-less channels never exchange."""
-                    new = dict(c)
-                    for attr, rate in payload.items():
-                        if rate == 0.0:
-                            continue
-                        new[attr] = pallas_halo_step(
-                            c[attr], ring_of(c[attr], ns), origin, gshape,
-                            rate, offsets, interpret=interpret, nsteps=ns,
-                            compute_dtype=cdt)
-                    return new
-            elif kind == "composed":
-                from ..ops.composed_stencil import composed_halo_step
+            if kind == "field":
+                def rings_of(c, ns):
+                    """Every channel's depth-``ns`` ring: outflows read
+                    modulators on ghost cells too."""
+                    return {k: ring_of(v, ns) for k, v in c.items()}
 
-                def chunk(c, ns):
-                    """ns flow steps as ONE composed (2·ns+1)² pass per
-                    depth-``ns`` exchange — interior tiles run the tap
-                    filter, near-global-edge tiles the exact iterated
-                    path (remainder chunks compose at their own ns)."""
-                    new = dict(c)
-                    for attr, rate in payload.items():
-                        if rate == 0.0:
-                            continue
-                        new[attr] = composed_halo_step(
-                            c[attr], ring_of(c[attr], ns), origin, gshape,
-                            rate, ns, offsets, interpret=interpret,
-                            compute_dtype=cdt)
-                    return new
-            else:
-                def chunk(c, ns):
-                    """One depth-``ns`` exchange of EVERY channel, then
-                    ns fused multi-channel steps in one kernel call."""
-                    rings = {k: ring_of(v, ns) for k, v in c.items()}
+                def chunk(c, rings, ns):
+                    """ns fused multi-channel steps in one kernel call."""
                     return pallas_field_halo_step(
                         c, rings, origin, gshape, payload, offsets,
                         interpret=interpret, nsteps=ns, compute_dtype=cdt)
+            else:
+                if kind == "composed":
+                    from ..ops.composed_stencil import composed_halo_step
 
-            # dynamic trip count (n traced): q full-depth fused chunks,
-            # then a switch over the possible remainder depths — each
-            # branch instantiates the kernel at its own (static) nsteps
+                    def kernel(v, ring, rate, ns):
+                        """ns flow steps as ONE composed (2·ns+1)² pass:
+                        interior tiles run the tap filter, near-global-
+                        edge tiles the exact iterated path (remainder
+                        chunks compose at their own ns)."""
+                        return composed_halo_step(
+                            v, ring, origin, gshape, rate, ns, offsets,
+                            interpret=interpret, compute_dtype=cdt)
+                else:
+                    def kernel(v, ring, rate, ns):
+                        """ns fused steps in one kernel call."""
+                        return pallas_halo_step(
+                            v, ring, origin, gshape, rate, offsets,
+                            interpret=interpret, nsteps=ns,
+                            compute_dtype=cdt)
+
+                def rings_of(c, ns):
+                    """The depth-``ns`` rings of the channels that flow
+                    (flow-less channels never exchange)."""
+                    return {a: ring_of(c[a], ns)
+                            for a, rate in payload.items() if rate != 0.0}
+
+                def chunk(c, rings, ns):
+                    new = dict(c)
+                    for attr, rate in payload.items():
+                        if rate != 0.0:
+                            new[attr] = kernel(c[attr], rings[attr], rate,
+                                               ns)
+                    return new
+
+            def full_pass(state):
+                """One full-depth pass; the next pass's rings are cut
+                from its output and carried in the loop state (cut from
+                the loop's carry, the thin ghost columns made XLA lay out
+                a column-major copy of the whole shard every pass)."""
+                c = chunk(*state, depth)
+                return c, rings_of(c, depth)
+
+            # dynamic trip count (n traced): q full-depth passes, two per
+            # loop iteration (paired_loop: no copy of the state before
+            # each kernel pass), then a switch over the possible
+            # remainder depths — each branch instantiates the kernel at
+            # its own (static) nsteps and ships only the rings it
+            # consumes
             q = n // depth
-            out = lax.fori_loop(0, q, lambda i, c: chunk(c, depth), values)
+            out, _ = paired_loop(
+                full_pass, q, (values, rings_of(values, depth)),
+                odd=lambda s: (chunk(*s, depth), s[1]))
             if depth > 1:
                 branches = [lambda c: c] + [
-                    (lambda d: lambda c: chunk(c, d))(d)
+                    (lambda d: lambda c: chunk(c, rings_of(c, d), d))(d)
                     for d in range(1, depth)]
                 out = lax.switch(n - q * depth, branches, out)
             return out

@@ -7,19 +7,21 @@ The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every xdist worker imports this
 file."""
 
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
-from mpi_model_tpu import Coupled, Diffusion
+from mpi_model_tpu import CellularSpace, Coupled, Diffusion, Model
 from mpi_model_tpu.core.cell import MOORE_OFFSETS
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     # a compile for a described chip is written to the persistent cache
     # but cannot be read back without one: keep the cache off here
@@ -32,9 +34,16 @@ def one_chip():
     except Exception as e:  # analysis: ignore[broad-except] — no TPU compiler here
         jax.config.update("jax_enable_compilation_cache", was)
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e.devices[0])
 
 
 def _sds(shape, dtype, sharding):
@@ -114,3 +123,76 @@ def test_fused_active_kernel(one_chip, dtype, k, ring):
              lanes, _sds((), "int32", one_chip), lanes,
              _sds((2,), "int32", one_chip),
              kernels=["mm_active_compute", "mm_active_scatter"])
+
+
+def _loop_bodies(text):
+    """The instructions of each while loop's body computation."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            name = line.split()[0]
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    return [comps[b] for b in set(re.findall(r"body=(%[\w.\-]+)", text))]
+
+
+def _assert_paired_body(text, kernel, state):
+    """The kernel passes run two to a loop iteration, and no loop body
+    that does so copies the whole state (``state``, e.g.
+    ``f32[24576,24576]``). One body may hold one pass and the copy: the
+    odd pass, whose loop runs at most once per call."""
+    state = re.escape(state)
+    copies, passes = [], []
+    for body in _loop_bodies(text):
+        copies.append(sum(bool(re.search(rf"= {state}\S* copy\(", ln))
+                          for ln in body))
+        passes.append(sum(f"%{kernel}." in ln.split("=")[0] for ln in body))
+    # (kernel calls, whole-state copies) of each loop body
+    assert sorted(zip(passes, copies)) == [(1, 1), (2, 0)]
+
+
+def test_dense_horizon_loop_copies_no_state(v5e, one_chip):
+    """The one-chip horizon cell's runner (16384² bf16, 4 fused steps per
+    Pallas pass): a loop of one pass per iteration copies the whole
+    state before every pass, because the kernel cannot write into the
+    carry it reads."""
+    from mpi_model_tpu.models.model import _loop_runner
+
+    n = 16384
+    grid = _sds((n, n), "bfloat16", one_chip)
+    space = CellularSpace({"value": grid}, *grid.shape)  # shapes only
+    # make_step takes interpret mode from the default device
+    with jax.default_device(v5e.devices[0]):
+        step = Model(Diffusion(0.1)).make_step(space, impl="pallas",
+                                               substeps=4)
+    assert step.impl == "pallas"
+    count = _sds((), "int32", one_chip)
+    text = _loop_runner(step, None).lower(
+        {"value": grid}, count, count).compile().as_text()
+    _assert_paired_body(text, "mm_stencil", f"bf16[{n},{n}]")
+
+
+def test_sharded_horizon_loop_copies_no_shard(v5e):
+    """The four-chip horizon cell's runner (49152² f32 on a 2x2 mesh,
+    halo depth 4): besides the carry copy, the ghost columns cut from
+    the carry made XLA lay a transposed copy of the whole shard; the
+    rings are now cut from each kernel's output and carried."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from mpi_model_tpu.parallel import ShardMapExecutor
+    from mpi_model_tpu.parallel.mesh import grid_spec
+
+    n = 49152
+    mesh = Mesh(np.array(v5e.devices).reshape(2, 2), ("x", "y"))
+    grid = jax.ShapeDtypeStruct((n, n), jnp.float32,
+                                sharding=NamedSharding(mesh, grid_spec(mesh)))
+    space = CellularSpace({"value": grid}, *grid.shape)  # shapes only
+    model = Model(Diffusion(0.1))
+    ex = ShardMapExecutor(mesh, step_impl="pallas", halo_depth=4)
+    runner = ex._build_pallas_runner(model, space,
+                                     ex._pallas_plan(model, space))
+    count = jax.ShapeDtypeStruct((), jnp.int32,
+                                 sharding=NamedSharding(mesh, P()))
+    text = runner.lower({"value": grid}, count).compile().as_text()
+    _assert_paired_body(text, "mm_stencil_halo", f"f32[{n // 2},{n // 2}]")
