@@ -48,7 +48,7 @@ class Window:
     failed: int
     metrics: dict          # end-to-end name -> value
     work: dict             # what the roofline readers count
-    counters: dict         # program counters over the window
+    counters: dict         # program counters over the window (spec.py)
     check: callable        # (store=None) -> the numbers compared
     errors: list
     #: a few numbers about the window itself, printed on stderr
@@ -75,7 +75,8 @@ def _grid_window(cell, system, seed, seconds, annotate) -> Window:
             "cells": h * w, "state_bytes": state_bytes,
             "elapsed_s": res.elapsed_s}
     cuts, report = res.sample
-    notes = {"calls": res.calls, "elapsed_s": res.elapsed_s}
+    counters = program_counters(res.reports)
+    notes = dict(counters, elapsed_s=res.elapsed_s)
     # the program's state goes before the reference runs
     system.space = None
     del res
@@ -87,7 +88,19 @@ def _grid_window(cell, system, seed, seconds, annotate) -> Window:
     rate = work["calls"] * system.steps * h * w / work["elapsed_s"]
     return Window(attempted=work["calls"], failed=0,
                   metrics={"cell_updates_per_s": rate}, work=work,
-                  counters={}, check=check, errors=[], notes=notes)
+                  counters=counters, check=check, errors=[], notes=notes)
+
+
+def program_counters(reports: list) -> dict:
+    """``calls``, and for each number (an ``int`` or ``float``, not a
+    ``bool``) in the calls' ``backend_report``, its sum over the calls
+    as ``report.<key>``. Strings, lists and None are left out."""
+    out = {"calls": len(reports)}
+    for r in reports:
+        for k, v in (r.backend_report or {}).items():
+            if isinstance(v, (int, float)) and not isinstance(v, bool):
+                out["report." + k] = out.get("report." + k, 0) + v
+    return out
 
 
 def _closed_window(cell, system, seed, seconds, annotate) -> Window:
@@ -137,12 +150,14 @@ def build(cell, seed: int, devices, executor_overrides=None):
     off so that it gives a reading rather than an exception."""
     tf = cell.traffic
     if tf["loop"] == "closed":
-        return systems.ServiceSystem(cell.config, tf, seed, devices)
+        return systems.ServiceSystem(cell.config, tf, seed, devices,
+                                     root=cell.root)
     if tf["loop"] == "horizon":
         return systems.GridSystem(cell.config, seed, devices,
                                   tf["steps_per_call"],
                                   executor_overrides=executor_overrides,
-                                  check_conservation=not executor_overrides)
+                                  check_conservation=not executor_overrides,
+                                  root=cell.root)
     raise ValueError(f"unknown traffic loop {tf['loop']!r}")
 
 

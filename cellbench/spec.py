@@ -3,8 +3,18 @@
 A cell (one entry of ``workloads``) names a configuration and a traffic
 mix. The configuration's file is the one its ``configs`` entry names;
 the traffic mix is ``cellbench/traffic/<traffic>.json``; a per-layer
-metric ``<name>`` is read by ``cellbench/metrics/<name>.py``. A later
-change adds a cell by adding such files and entries, and edits none.
+metric ``<name>`` is read by ``cellbench/metrics/<name>.py``; a channel
+whose ``init`` is ``{"maker": <name>, ...}`` is made by
+``cellbench/inits/<name>.py``. A later change adds a cell by adding such
+files and entries, and edits none.
+
+A reader's ``read(ctx)`` gets the trace, the configuration, the traffic,
+the window's ``work`` and its ``counters``. A grid cell's counters are
+``calls`` and, for each number (an ``int`` or ``float``, not a
+``bool``) in the program's ``Report.backend_report``,
+``report.<key>``: its sum over the window's calls. A mean the program
+reports per call, such as ``mean_active_fraction``, is its sum over
+``calls``.
 """
 
 from __future__ import annotations
@@ -16,6 +26,7 @@ import os
 
 TRAFFIC_DIR = os.path.join("cellbench", "traffic")
 METRICS_DIR = os.path.join("cellbench", "metrics")
+INITS_DIR = os.path.join("cellbench", "inits")
 
 
 @dataclasses.dataclass
@@ -67,8 +78,18 @@ def metric_module(root: str, name: str):
     while not os.path.exists(path) and "." in stem:
         stem = stem.rsplit(".", 1)[0]
         path = os.path.join(root, METRICS_DIR, stem + ".py")
+    return _load(path, "cellbench_metric_" + name)
+
+
+def init_maker(root: str, name: str):
+    """The ``make`` function of ``cellbench/inits/<name>.py``."""
+    return _load(os.path.join(root, INITS_DIR, name + ".py"),
+                 "cellbench_init_" + name).make
+
+
+def _load(path: str, module: str):
     spec = importlib.util.spec_from_file_location(
-        "cellbench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+        module.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod

@@ -10,9 +10,12 @@ Everything here goes through the program's public entry points:
 - ``executor``: ``{"kind": "serial" | "shard_map", ...}`` (the rest
   are the executor's own arguments; ``mesh`` is the shard_map mesh) or
   ``{"kind": "service", "max_batch": n, "impl": ...}``;
-- ``grid``, ``dtype`` and ``init`` (per channel, ``[low, high)`` of a
-  uniform draw, or a constant), made on the device from the seed in one
-  jitted call, sharded over the mesh where there is one.
+- ``grid``, ``dtype`` and ``init``: per channel, ``[low, high)`` of a
+  uniform draw, a constant, or ``{"maker": <name>, ...}``, whose
+  ``cellbench/inits/<name>.py`` ``make(key, shape, params)`` returns
+  the channel in f32 (``params`` is the whole object). All channels
+  are made on the device from the seed in one jitted call, sharded
+  over the mesh where there is one.
 """
 
 from __future__ import annotations
@@ -35,21 +38,29 @@ def _dtype(name):
     return None if name is None else jnp.dtype(name)
 
 
-def make_values(config: dict, seed: int, count: int = 0, sharding=None):
+def make_values(config: dict, seed: int, count: int = 0, sharding=None,
+                *, root: str):
     """The initial channels, drawn on the device in one jitted call: one
-    state, or with ``count`` a list of that many independent ones."""
+    state, or with ``count`` a list of that many independent ones.
+    Makers are found under the checkout ``root``."""
     import jax
     import jax.numpy as jnp
+
+    from cellbench.spec import init_maker
 
     shape = tuple(config["grid"])
     dtype = jnp.dtype(config["dtype"])
     init = config["init"]
+    makers = {ch: init_maker(root, s["maker"])
+              for ch, s in init.items() if isinstance(s, dict)}
 
     def one(key):
         keys = jax.random.split(key, len(init))
         out = {}
         for k, (ch, spec) in zip(keys, init.items()):
-            if isinstance(spec, list):
+            if ch in makers:
+                x = makers[ch](k, shape, spec)
+            elif isinstance(spec, list):
                 lo, hi = spec
                 x = jax.random.uniform(k, shape, jnp.float32, lo, hi)
             else:
@@ -85,6 +96,10 @@ def build_executor(spec: dict, devices):
     kw = {k: v for k, v in spec.items() if k not in ("kind", "mesh")}
     if "compute_dtype" in kw:
         kw["compute_dtype"] = _dtype(kw["compute_dtype"])
+    if "active_opts" in kw:
+        # a tile comes as a JSON list; the executor keys its cache on it
+        kw["active_opts"] = {k: tuple(v) if isinstance(v, list) else v
+                             for k, v in kw["active_opts"].items()}
     if spec["kind"] == "serial":
         return SerialExecutor(**kw)
     if spec["kind"] == "shard_map":
@@ -98,7 +113,8 @@ class GridSystem:
     """One model stepped over one grid by ``Model.execute``."""
 
     def __init__(self, config: dict, seed: int, devices, steps: int,
-                 executor_overrides=None, check_conservation: bool = True):
+                 executor_overrides=None, check_conservation: bool = True,
+                 *, root: str):
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -115,7 +131,7 @@ class GridSystem:
         sharding = (NamedSharding(mesh, P(*mesh.axis_names))
                     if mesh is not None else None)
         with jax.default_device(devices[0]):
-            values = make_values(config, seed, sharding=sharding)
+            values = make_values(config, seed, sharding=sharding, root=root)
         h, w = config["grid"]
         self.space = CellularSpace(values=values, dim_x=h, dim_y=w)
 
@@ -135,7 +151,8 @@ class ServiceSystem:
     """Scenarios served by an ``AsyncEnsembleService``: a pool of input
     grids and one model per rate of the mix."""
 
-    def __init__(self, config: dict, traffic: dict, seed: int, devices):
+    def __init__(self, config: dict, traffic: dict, seed: int, devices,
+                 *, root: str):
         import jax
 
         import mpi_model_tpu as mm
@@ -149,7 +166,8 @@ class ServiceSystem:
         self.buckets = buckets_for(int(ex["max_batch"]))
         h, w = config["grid"]
         with jax.default_device(devices[0]):
-            pool = make_values(config, seed, count=int(traffic["pool"]))
+            pool = make_values(config, seed, count=int(traffic["pool"]),
+                               root=root)
         self.pool = [CellularSpace(values=v, dim_x=h, dim_y=w) for v in pool]
         self.models = {r: mm.Model([mm.Diffusion(float(r))])
                        for r in traffic["rates"]}

@@ -39,6 +39,8 @@ class HorizonResult:
     state: Any
     #: what ``take`` kept of the call the seed drew
     sample: Any
+    #: each call's report, in order (references, nothing copied)
+    reports: list
 
 
 def _whole(state, out, report):
@@ -53,11 +55,13 @@ def horizon(call: Callable, state, seconds: float, *, sample_at: float,
     calls have taken ``seconds``. Of the first call that starts at or
     after ``sample_at`` of the window (a fraction drawn from the seed),
     ``take(input, output, report)`` keeps what the correctness check
-    needs; its time is not the program's and is left out."""
+    needs; its time is not the program's and is left out. Every call's
+    report is kept for the window's counters."""
     sample = None
     sampled = False
     calls = 0
     elapsed = 0.0
+    reports = []
     while True:
         start = elapsed
         t = clock()
@@ -69,9 +73,10 @@ def horizon(call: Callable, state, seconds: float, *, sample_at: float,
                 sample, sampled = take(state, out, report), True
         state = out
         calls += 1
+        reports.append(report)
         if elapsed >= seconds:
             break
-    return HorizonResult(calls, elapsed, state, sample)
+    return HorizonResult(calls, elapsed, state, sample, reports)
 
 
 @dataclasses.dataclass

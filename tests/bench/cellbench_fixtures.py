@@ -19,12 +19,13 @@ REPO = Path(__file__).resolve().parents[2]
 #: the cut of a configuration that names none
 DEFAULT_TINY = {"grid": [64, 64]}
 
-PARTS = ("metrics", "traffic", "configs")
+PARTS = ("metrics", "traffic", "configs", "inits")
 
 
 def copy_benchmark(dst: Path, src: Path = REPO) -> Path:
     """The benchmark's own files (``BENCHMARK.json``, configurations,
-    traffic mixes, metric readers) of ``src``, copied under ``dst``."""
+    traffic mixes, metric readers, initial-state makers) of ``src``,
+    copied under ``dst``."""
     for part in PARTS:
         shutil.copytree(src / "cellbench" / part, dst / "cellbench" / part,
                         ignore=shutil.ignore_patterns("__pycache__"))

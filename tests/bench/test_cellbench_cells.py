@@ -81,7 +81,7 @@ def test_loading_a_cell_by_name():
     assert [m["name"] for m in cell.end_to_end] == ["cell_updates_per_s",
                                                     "setup_s"]
     assert {m["name"] for m in cell.per_layer} == {
-        "ir_step_roofline", "device_idle_pct.grid"}
+        "ir_step_roofline", "device_idle_pct.grid", "totals_idle_pct.grid"}
     with pytest.raises(KeyError, match="no workload"):
         spec.load_cell(str(REPO), "no-such-cell")
 
